@@ -31,24 +31,19 @@ the modeled decode HBM-bytes-per-token of each route and the int8 pool's
 context-per-byte ratio. check_serving_gate.py bounds the fused route's TPOT
 and the int8 capacity from these rows.
 
-Multi-device row: unless ``--no-multi-device``, the bench re-execs itself in
-a subprocess with 8 forced host devices (``XLA_FLAGS``, as in
-test_distributed) and ``--tp 2``, running the continuous engine
-tensor-parallel on a (4, 2) data x model mesh, and merges the result in as
+Multi-device row: unless ``--no-multi-device``, the bench serves every mode
+again in the same process, tensor-parallel on a (n/2, 2) data x model mesh
+over ``jax.devices()``, and merges the result in as
 ``results[mode]["continuous_tp2"]`` — same workload trace, token-for-token
-the same outputs, so the row isolates the sharding overhead/benefit.
-(On CPU hosts the row measures dispatch overhead, not kernel speedup; on
-real accelerators the same flag spreads the weight/KV traffic over the
-mesh.)
+the same outputs, so the row isolates the sharding overhead/benefit. With
+one device the row is skipped and the bench says so (on CPU, set
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` to get eight). No
+child process is started: a chip belongs to the process that imported JAX.
 """
 from __future__ import annotations
 
 import argparse
 import json
-import os
-import subprocess
-import sys
-import tempfile
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -409,47 +404,21 @@ def bench_mode(mode: str, args, mesh=None) -> Dict:
 
 
 def multi_device_row(args) -> Optional[Dict]:
-    """Re-exec the bench with 8 forced host devices + ``--tp 2`` and return
-    the child's per-mode continuous results (None if the child fails)."""
-    child_args = [
-        sys.executable, os.path.abspath(__file__),
-        "--arch", args.arch, "--modes", args.modes,
-        "--requests", str(args.requests),
-        "--arrival-rate", str(args.arrival_rate),
-        "--n-slots", str(args.n_slots), "--max-len", str(args.max_len),
-        "--min-prompt", str(args.min_prompt), "--max-prompt", str(args.max_prompt),
-        "--min-new", str(args.min_new), "--max-new", str(args.max_new),
-        "--kv-block-size", str(args.kv_block_size),
-        "--prefill-chunk", str(args.prefill_chunk),
-        "--sys-prompt", str(args.sys_prompt),
-        "--seed", str(args.seed), "--tp", "2", "--no-multi-device",
-    ]
-    if args.no_warmup:
-        child_args.append("--no-warmup")
-    with tempfile.NamedTemporaryFile(suffix=".json", delete=False) as tmp:
-        out_path = tmp.name
-    child_args += ["--out", out_path]
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    try:
-        try:
-            r = subprocess.run(child_args, env=env, capture_output=True, text=True,
-                               timeout=3600)
-        except (subprocess.TimeoutExpired, OSError) as e:
-            print(f"[multi-device] child did not finish: {e!r}")
-            return None
-        if r.returncode != 0:
-            print(f"[multi-device] child failed:\n{r.stderr[-2000:]}")
-            return None
-        with open(out_path) as fh:
-            return json.load(fh)["results"]
-    finally:
-        try:
-            os.unlink(out_path)
-        except OSError:
-            pass
+    """Every mode's continuous + paged rows served tensor-parallel, in this
+    process, on a (n/2, 2) data x model mesh over ``jax.devices()``. Returns
+    None (and says why) when fewer than two devices, or an odd count, are
+    visible; a failing row raises."""
+    n = len(jax.devices())
+    if n < 2 or n % 2:
+        print(f"[multi-device] skipped: {n} device(s) visible; the tp=2 row "
+              "needs an even count >= 2 (on CPU: XLA_FLAGS="
+              "--xla_force_host_platform_device_count=8)")
+        return None
+    from repro.launch.serve import build_serve_mesh
+
+    mesh = build_serve_mesh(2, "")
+    print(f"[multi-device] mesh {dict(mesh.shape)}")
+    return {m: bench_mode(m, args, mesh=mesh) for m in args.modes.split(",")}
 
 
 def main(argv=None) -> int:
@@ -487,7 +456,7 @@ def main(argv=None) -> int:
                     help="run the continuous engine tensor-parallel on a "
                          "(n_dev/tp, tp) data x model mesh (0 = off)")
     ap.add_argument("--no-multi-device", action="store_true",
-                    help="skip the 8-host-device --tp 2 subprocess row")
+                    help="skip the in-process tp=2 row over the local devices")
     ap.add_argument("--out", default="BENCH_serving.json")
     ap.add_argument("--smoke", action="store_true",
                     help="capped run for CI: bika only, 8 requests, no "
@@ -541,7 +510,8 @@ def main(argv=None) -> int:
         "speculative": speculative,
         "tp": args.tp or None,
         "multi_device": (
-            {"forced_host_devices": 8, "mesh": {"data": 4, "model": 2},
+            {"devices": len(jax.devices()),
+             "mesh": {"data": len(jax.devices()) // 2, "model": 2},
              "row": "continuous_tp2"} if multi is not None else None
         ),
         "results": results,

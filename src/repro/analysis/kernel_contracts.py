@@ -43,6 +43,7 @@ import jax.numpy as jnp
 from repro.configs import ARCH_NAMES, get_config
 from repro.hwsim.resource import KERNEL_VMEM_BUDGET
 from repro.kernels import autotune, ops
+from repro.kernels.bnn_matmul import PACKED_WINDOW
 
 from .report import Finding, Report
 
@@ -116,8 +117,8 @@ def _resolve(route: str, m: int, k: int, n: int,
     if info.get("packed"):
         bk = max((min(bk, k) // 8) * 8, 8)  # ops._bnn_packed_impl byte rule
     sub = bl.get("block_k_sub")
-    bks = autotune.pick_block_k_sub(bm, bn, bk, requested=sub,
-                                    multiple=8 if info.get("packed") else 1)
+    align = {"align": PACKED_WINDOW} if info.get("packed") else {}
+    bks = autotune.pick_block_k_sub(bm, bn, bk, requested=sub, **align)
     return dict(block_m=bm, block_n=bn, block_k=bk, block_k_sub=bks)
 
 
@@ -155,7 +156,7 @@ def matmul_vmem_bytes(route: str, bl: Dict[str, int]) -> int:
 def paged_attn_vmem_bytes(c: int, bs: int, bh: int, g: int, d: int,
                           *, quantized: bool = False) -> int:
     """One grid step of the fused paged-attention kernel: q/out windows
-    (1, C, bh*g, D), k/v pool windows (1, bs, bh, D), per-block scales when
+    (1, bh, g*C, D), k/v pool windows (1, bs, bh, D), per-block scales when
     quantized, and the online-softmax scratch (m, l, acc)."""
     kv_w = 1 if quantized else 4
     q_out = 2 * c * bh * g * d * 4
@@ -216,7 +217,7 @@ def check_matmul_contract(route: str, m: int, k: int, n: int,
                 "KCV002", where,
                 f"packed K beats must slice whole bytes: block_k={bk}, "
                 f"block_k_sub={bks}",
-                "use pick_block_k_sub(..., multiple=8)",
+                "use pick_block_k_sub (sub-tiles are multiples of 8)",
                 block_k=bk, block_k_sub=bks))
     # KCV003: last-step index-map bounds per axis (block-index maps i -> i*b)
     for dim, (p, b, gdim) in dict(

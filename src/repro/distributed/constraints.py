@@ -12,13 +12,13 @@ Outside a mesh context (unit tests, single CPU) it is the identity.
 """
 from __future__ import annotations
 
-import warnings
+import contextlib
 from typing import Optional, Tuple
 
 import jax
 from jax.sharding import PartitionSpec
 
-__all__ = ["constrain", "activation_rules"]
+__all__ = ["constrain", "activation_rules", "mesh_context"]
 
 # logical activation-dim name -> mesh axes (late-bound against the context mesh)
 ACT_RULES = {
@@ -34,18 +34,18 @@ ACT_RULES = {
 }
 
 
-def _context_mesh():
-    try:
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore")
-            from jax.interpreters import pxla
+def mesh_context(mesh):
+    """``jax.set_mesh(mesh)`` as a context manager, or a no-op for None.
+    Serving and training enter it around tracing and running, so the
+    in-model constraints and the TP kernel routes see the mesh."""
+    return jax.set_mesh(mesh) if mesh is not None else contextlib.nullcontext()
 
-            mesh = pxla.thread_resources.env.physical_mesh
-        if mesh.empty:
-            return None
-        return mesh
-    except Exception:
-        return None
+
+def _context_mesh():
+    """The abstract mesh set by ``mesh_context``, or None outside one.
+    Readable inside ``jit`` (it carries axis names and sizes only)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def activation_rules(name: Optional[str], mesh) -> Tuple[str, ...]:

@@ -24,19 +24,8 @@ def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
 
 
 def abstract_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Device-free mesh for spec-level tests and dry-runs.
-
-    ``jax.sharding.AbstractMesh`` changed its constructor across JAX
-    releases: newer versions take ``(axis_sizes, axis_names)`` while e.g.
-    0.4.37 takes a single ``((name, size), ...)`` shape tuple (the two-arg
-    form there raises TypeError("'int' object is not iterable") inside
-    jax._src.mesh). Normalize both here so callers never touch the raw
-    constructor."""
-    am = jax.sharding.AbstractMesh
-    try:
-        return am(tuple(shape), tuple(axes))
-    except TypeError:
-        return am(tuple(zip(axes, shape)))
+    """Device-free mesh for spec-level tests and dry-runs."""
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
 
 
 def single_device_mesh(axes: Tuple[str, ...] = ("data", "model")) -> Mesh:

@@ -17,7 +17,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as PS
 
 __all__ = ["pipeline_apply"]
@@ -87,10 +86,10 @@ def pipeline_apply(
         return outs
 
     other = tuple(a for a in mesh.axis_names if a != axis)
-    return shard_map(
+    return jax.shard_map(
         run,
         mesh=mesh,
         in_specs=(param_specs, PS()),
         out_specs=PS(),
-        check_rep=False,
+        check_vma=False,
     )(stage_params, x)

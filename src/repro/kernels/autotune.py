@@ -12,16 +12,18 @@ Two layers, both deterministic by default:
      MXU path (qnn8) deepens K further because its operand blocks are 4x
      smaller than f32 at equal tile counts.
   2. ``measured_blocks(...)`` — an optional measured search that times the
-     real kernel call over a candidate list and persists the winner in an
-     on-disk JSON cache (env ``REPRO_AUTOTUNE_CACHE`` or
-     ``~/.cache/repro/autotune.json``), keyed on ``backend:path:MxKxN``.
+     real kernel call over a candidate list and keeps the winner in memory,
+     and in the JSON file named by ``REPRO_AUTOTUNE_CACHE`` when that is
+     set, keyed on ``backend:path:MxKxN``. With the variable unset no file
+     feeds the tiles: a run compiles from the heuristic table alone.
 
 ``get_blocks`` merges heuristic < cached < explicit caller overrides and then
 clamps to legal tile sizes for the (padded) problem, so every kernel wrapper
 funnels through one resolution point.  ``pick_block_k_sub`` chooses the
 sub-tile depth of the vectorized beat loop (kernels/cac_matmul.py): the
-largest divisor of block_k whose (bm, bk_sub, bn) broadcast-compare stays
-inside the VREG working-set budget.
+largest sublane-aligned divisor of the block's 128-lane read window
+(kernels/beats.py) whose (bm, bk_sub, bn) broadcast-compare stays inside the
+VREG working-set budget.
 """
 from __future__ import annotations
 
@@ -32,6 +34,8 @@ import threading
 from typing import Dict, List, Optional, Tuple
 
 import jax
+
+from .beats import LANE, beat_window
 
 __all__ = [
     "DEFAULT_BLOCKS",
@@ -87,7 +91,7 @@ _INT_PATHS = ("qnn8", "qnn")
 # so entries tuned before the registry rename keep being honored.
 _LEGACY_PATH_ALIASES = {"qnn8": "qnn"}
 
-_SUBLANE, _LANE = 8, 128  # f32 min tile (sublane x lane)
+_SUBLANE, _LANE = 8, LANE  # f32 min tile (sublane x lane)
 
 
 def _round_up(v: int, b: int) -> int:
@@ -123,26 +127,20 @@ def _clamp(m: int, k: int, n: int, bl: Dict[str, int]) -> Dict[str, int]:
 
 
 def pick_block_k_sub(bm: int, bn: int, bk: int, requested: Optional[int] = None,
-                     budget: int = SUBTILE_BUDGET, multiple: int = 1) -> int:
-    """Largest divisor of bk such that bm * bk_sub * bn <= budget (>= 1).
-
-    ``multiple`` additionally constrains the result to a multiple of that
-    value when one divides bk (the packed-bitplane kernel needs bk_sub % 8
-    == 0 so each beat slices whole uint8 rows); falls back to the
-    unconstrained divisor when bk itself has no such divisor <= cap."""
+                     budget: int = SUBTILE_BUDGET, align: int = LANE) -> int:
+    """Beat width of a block with K extent ``bk``: a divisor of the block's
+    read window (``beats.beat_window(bk, align)``: ``align`` when it divides
+    bk, else bk itself) that is a multiple of 8 (the f32 sublane tile, so
+    every beat's operand rows start on a tile row, and a packed beat slices
+    whole bytes). Returns the largest such width with ``bm * bk_sub * bn <=
+    budget`` (or ``<= requested`` when given), else the smallest legal
+    width. A window with no such divisor is a single beat."""
+    window = beat_window(bk, align)
+    legal = [d for d in range(_SUBLANE, window + 1, _SUBLANE)
+             if window % d == 0] or [window]
     cap = requested if requested else max(budget // max(bm * bn, 1), 1)
-    bks = max(min(cap, bk), 1)
-    while bk % bks:
-        bks -= 1
-    if multiple > 1 and bks % multiple:
-        cand = (bks // multiple) * multiple
-        while cand >= multiple and bk % cand:
-            cand -= multiple
-        if cand >= multiple:
-            bks = cand
-        elif bk % multiple == 0:
-            bks = multiple
-    return bks
+    fits = [d for d in legal if d <= cap]
+    return max(fits) if fits else min(legal)
 
 
 # ---------------------------------------------------------------------------
@@ -153,11 +151,11 @@ _cache_lock = threading.Lock()
 _cache: Optional[Dict[str, Dict[str, int]]] = None
 
 
-def cache_path() -> str:
-    return os.environ.get(
-        "REPRO_AUTOTUNE_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "repro", "autotune.json"),
-    )
+def cache_path() -> Optional[str]:
+    """The measured-block cache file named by ``REPRO_AUTOTUNE_CACHE``, or
+    None: then no file feeds the tiles, which come from the heuristic table
+    (and from searches measured in this process)."""
+    return os.environ.get("REPRO_AUTOTUNE_CACHE") or None
 
 
 def cache_key(path: str, m: int, k: int, n: int) -> str:
@@ -260,11 +258,14 @@ def _load_cache() -> Dict[str, Dict[str, int]]:
     with _cache_lock:
         if _cache is None:
             _invalid.clear()
-            try:
-                with open(cache_path()) as fh:
-                    raw = json.load(fh)
-            except (OSError, ValueError):
-                raw = {}
+            path = cache_path()
+            raw = {}
+            if path is not None:
+                try:
+                    with open(path) as fh:
+                        raw = json.load(fh)
+                except (OSError, ValueError):
+                    raw = {}
             if not isinstance(raw, dict):
                 raw = {}
             _cache = {}
@@ -285,6 +286,8 @@ def _store_cache(key: str, blocks: Dict[str, int]) -> None:
         cur[key] = {k_: int(v) for k_, v in blocks.items()}
         _cache = cur
         f = cache_path()
+        if f is None:
+            return  # no cache file named: the winner lives in memory only
         try:
             os.makedirs(os.path.dirname(f), exist_ok=True)
             tmp = f + ".tmp"
@@ -360,8 +363,8 @@ def measured_blocks(
 ) -> Dict[str, int]:
     """Time the real kernel over a candidate list; persist + return the best.
 
-    The measured winner goes into the on-disk cache (``cache_path()``; set
-    ``REPRO_AUTOTUNE_CACHE`` to redirect it) so later ``get_blocks`` calls
+    The measured winner goes into the cache (in memory, and the file
+    ``REPRO_AUTOTUNE_CACHE`` names when set) so later ``get_blocks`` calls
     for the same (backend, path, shape) pick it up without re-timing."""
     import time
 

@@ -36,8 +36,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .autotune import pick_block_k_sub
+from .beats import beat_slices, beat_window, fold_windows
 
 __all__ = [
+    "PACKED_WINDOW",
     "bnn_matmul_kernel_call",
     "bnn_packed_matmul_kernel_call",
     "bnn_bwd_dx_call",
@@ -45,12 +47,9 @@ __all__ = [
 ]
 
 
-def _slice0(a: jax.Array, i0, size: int) -> jax.Array:
-    return jax.lax.dynamic_slice_in_dim(a, i0, size, axis=0)
-
-
-def _slice1(a: jax.Array, i0, size: int) -> jax.Array:
-    return jax.lax.dynamic_slice_in_dim(a, i0, size, axis=1)
+# K rows per read window of the packed kernel: 32 uint8 bitplane rows, one
+# whole int8 sublane tile, so each window starts on a tile row.
+PACKED_WINDOW = 256
 
 
 def _sgn(a: jax.Array) -> jax.Array:
@@ -67,19 +66,18 @@ def _bnn_kernel(x_ref, w_ref, o_ref, *, bk_sub: int):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...].astype(jnp.float32)  # (bm, bk)
-    w = w_ref[...].astype(jnp.float32)  # (bk, bn)
-    bk = x.shape[1]
+    bk = x_ref.shape[1]
+    win = beat_window(bk)
 
-    def beat(i, acc):
-        k0 = i * bk_sub
-        xs = _sgn(_slice1(x, k0, bk_sub))  # (bm, bk_sub)
-        ws = _sgn(_slice0(w, k0, bk_sub))  # (bk_sub, bn)
-        return acc + jnp.dot(xs, ws, preferred_element_type=jnp.float32)
+    def window(k0, acc):
+        x = x_ref[:, pl.ds(k0, win)].astype(jnp.float32)  # (bm, win)
+        w = w_ref[pl.ds(k0, win), :].astype(jnp.float32)  # (win, bn)
+        for sl in beat_slices(win, bk_sub):
+            acc = acc + jnp.dot(_sgn(x[:, sl]), _sgn(w[sl]),
+                                preferred_element_type=jnp.float32)
+        return acc
 
-    acc = jax.lax.fori_loop(
-        0, bk // bk_sub, beat, jnp.zeros(o_ref.shape, jnp.float32)
-    )
+    acc = fold_windows(bk, win, window, jnp.zeros(o_ref.shape, jnp.float32))
     o_ref[...] += acc.astype(o_ref.dtype)
 
 
@@ -124,23 +122,25 @@ def _bnn_packed_kernel(x_ref, wp_ref, o_ref, *, bk_sub: int):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...].astype(jnp.float32)  # (bm, bk)
-    wp = wp_ref[...]  # (bk // 8, bn) uint8 bitplanes
-    bk = x.shape[1]
-    bn = wp.shape[1]
-    shifts = jnp.arange(8, dtype=jnp.uint8)
+    bk = x_ref.shape[1]
+    bn = wp_ref.shape[1]
+    # 256 unpacked rows = 32 packed uint8 rows: one full int8 sublane tile
+    win = beat_window(bk, PACKED_WINDOW)
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (1, 8, 1), 1)
 
-    def beat(i, acc):
-        k0 = i * bk_sub
-        xs = _sgn(_slice1(x, k0, bk_sub))  # (bm, bk_sub)
-        rows = _slice0(wp, k0 // 8, bk_sub // 8)  # (bk_sub/8, bn)
-        bits = (rows[:, None, :] >> shifts[:, None]) & 1  # (bk_sub/8, 8, bn)
-        ws = (2.0 * bits.reshape(bk_sub, bn).astype(jnp.float32)) - 1.0
-        return acc + jnp.dot(xs, ws, preferred_element_type=jnp.float32)
+    def window(k0, acc):
+        p0 = k0 // 8 if isinstance(k0, int) else pl.multiple_of(k0 // 8, win // 8)
+        x = x_ref[:, pl.ds(k0, win)].astype(jnp.float32)  # (bm, win)
+        wp = wp_ref[pl.ds(p0, win // 8), :].astype(jnp.int32)  # (win/8, bn)
+        for sl in beat_slices(win, bk_sub):
+            rows = wp[sl.start // 8:sl.stop // 8]  # (bk_sub/8, bn) bitplanes
+            bits = (rows[:, None, :] >> shifts) & 1  # (bk_sub/8, 8, bn)
+            ws = (2.0 * bits.reshape(bk_sub, bn).astype(jnp.float32)) - 1.0
+            acc = acc + jnp.dot(_sgn(x[:, sl]), ws,
+                                preferred_element_type=jnp.float32)
+        return acc
 
-    acc = jax.lax.fori_loop(
-        0, bk // bk_sub, beat, jnp.zeros(o_ref.shape, jnp.float32)
-    )
+    acc = fold_windows(bk, win, window, jnp.zeros(o_ref.shape, jnp.float32))
     o_ref[...] += acc.astype(o_ref.dtype)
 
 
@@ -167,7 +167,7 @@ def bnn_packed_matmul_kernel_call(
     bm, bn, bk = min(block_m, m), min(block_n, n), min(block_k, k)
     assert bk % 8 == 0, f"packed path needs block_k % 8 == 0, got {bk}"
     assert m % bm == 0 and n % bn == 0 and k % bk == 0
-    bks = pick_block_k_sub(bm, bn, bk, block_k_sub, multiple=8)
+    bks = pick_block_k_sub(bm, bn, bk, block_k_sub, align=PACKED_WINDOW)
     assert bks % 8 == 0 and bk % bks == 0
     return pl.pallas_call(
         functools.partial(_bnn_packed_kernel, bk_sub=bks),
@@ -196,19 +196,18 @@ def _bnn_bwd_dx_kernel(x_ref, w_ref, g_ref, dx_ref, *, bn_sub: int, n_j: int):
     def _init():
         dx_ref[...] = jnp.zeros_like(dx_ref)
 
-    w = w_ref[...].astype(jnp.float32)  # (bk, bn)
-    g = g_ref[...].astype(jnp.float32)  # (bm, bn)
-    bn = w.shape[1]
+    bn = w_ref.shape[1]
+    win = beat_window(bn)
 
-    def beat(i, acc):
-        n0 = i * bn_sub
-        gs = _slice1(g, n0, bn_sub)  # (bm, bn_sub)
-        ws = _sgn(_slice1(w, n0, bn_sub))  # (bk, bn_sub)
-        return acc + jnp.dot(gs, ws.T, preferred_element_type=jnp.float32)
+    def window(n0, acc):
+        g = g_ref[:, pl.ds(n0, win)].astype(jnp.float32)  # (bm, win)
+        w = w_ref[:, pl.ds(n0, win)].astype(jnp.float32)  # (bk, win)
+        for sl in beat_slices(win, bn_sub):
+            acc = acc + jnp.dot(g[:, sl], _sgn(w[:, sl]).T,
+                                preferred_element_type=jnp.float32)
+        return acc
 
-    acc = jax.lax.fori_loop(
-        0, bn // bn_sub, beat, jnp.zeros(dx_ref.shape, jnp.float32)
-    )
+    acc = fold_windows(bn, win, window, jnp.zeros(dx_ref.shape, jnp.float32))
     dx_ref[...] += acc.astype(dx_ref.dtype)
 
     @pl.when(j == n_j - 1)
@@ -251,19 +250,18 @@ def _bnn_bwd_dw_kernel(x_ref, w_ref, g_ref, dw_ref, *, bm_sub: int, n_i: int):
     def _init():
         dw_ref[...] = jnp.zeros_like(dw_ref)
 
-    x = x_ref[...].astype(jnp.float32)  # (bm, bk)
-    g = g_ref[...].astype(jnp.float32)  # (bm, bn)
-    bm = x.shape[0]
+    bm = x_ref.shape[0]
+    win = beat_window(bm)
 
-    def beat(t, acc):
-        m0 = t * bm_sub
-        xs = _sgn(_slice0(x, m0, bm_sub))  # (bm_sub, bk)
-        gs = _slice0(g, m0, bm_sub)  # (bm_sub, bn)
-        return acc + jnp.dot(xs.T, gs, preferred_element_type=jnp.float32)
+    def window(m0, acc):
+        x = x_ref[pl.ds(m0, win), :].astype(jnp.float32)  # (win, bk)
+        g = g_ref[pl.ds(m0, win), :].astype(jnp.float32)  # (win, bn)
+        for sl in beat_slices(win, bm_sub):
+            acc = acc + jnp.dot(_sgn(x[sl]).T, g[sl],
+                                preferred_element_type=jnp.float32)
+        return acc
 
-    acc = jax.lax.fori_loop(
-        0, bm // bm_sub, beat, jnp.zeros(dw_ref.shape, jnp.float32)
-    )
+    acc = fold_windows(bm, win, window, jnp.zeros(dw_ref.shape, jnp.float32))
     dw_ref[...] += acc.astype(dw_ref.dtype)
 
     @pl.when(i == n_i - 1)

@@ -7,13 +7,16 @@ that dataflow for the HBM->VMEM->VREG hierarchy:
   * grid (M/bm, N/bn, K/bk); the (bk, bn) threshold block stays resident in
     VMEM while activation blocks stream over the k-grid — "threshold-block-
     stationary", the BlockSpec rendition of weight-stationary systolic flow;
-  * inside a block, a fori_loop walks the bk inputs ``bk_sub`` rows at a
-    time; each step materializes a whole (bm, bk_sub, bn) broadcast-compare
-    in VREGs and reduces it on the VPU — a *vectorized* systolic beat
-    (bk_sub comparator waves issued as one fused compare-select-reduce),
-    replacing the old one-row-per-step serial schedule and its bk dynamic
-    row slices. ``bk_sub`` is the largest divisor of bk whose sub-tile fits
-    the VREG working-set budget (autotune.pick_block_k_sub);
+  * inside a block, the bk inputs are walked in read windows (kernels/
+    beats.py: 128 lanes, or the whole block when 128 does not divide it),
+    each read from the refs at a tile-aligned offset (``x_ref[:, pl.ds(k0,
+    win)]``) and cut into ``bk_sub``-wide beats by static slices — Mosaic
+    lowers neither a dynamic slice of a value nor an unaligned dynamic lane
+    offset. Each beat materializes a whole (bm, bk_sub, bn) broadcast-
+    compare and reduces it on the VPU — a *vectorized* systolic beat
+    (bk_sub comparator waves issued as one fused compare-select-reduce).
+    ``bk_sub`` is the largest sublane-aligned divisor of the window whose
+    sub-tile fits the working-set budget (autotune.pick_block_k_sub);
   * the out block accumulates across the k-grid (k innermost), so partial
     sums never round-trip to HBM.
 
@@ -24,7 +27,8 @@ whole point: at LM scale it would be ~10^12 elements. The one-pass
 recompute per block; the split dx / dw calls remain for A/B benchmarking.
 
 All kernels run under interpret=True on CPU (how tests validate them) and
-compile to Mosaic on real TPUs.
+compile to Mosaic on TPU (tests/test_tpu_compile.py compiles the serve kernel
+for a described v5e at smollm-360m widths).
 """
 from __future__ import annotations
 
@@ -36,6 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .autotune import pick_block_k_sub
+from .beats import beat_slices, beat_window, fold_windows
 
 __all__ = [
     "cac_matmul_kernel_call",
@@ -50,10 +55,6 @@ def _cdiv(a: int, b: int) -> int:
     return -(-a // b)
 
 
-def _slice_k(a: jax.Array, k0, bks: int, axis: int) -> jax.Array:
-    return jax.lax.dynamic_slice_in_dim(a, k0, bks, axis=axis)
-
-
 # ---------------------------------------------------------------------------
 # Hardware-form forward: y[m,n] = sum_k s[k,n] * Thres(x[m,k] - tau[k,n])
 # ---------------------------------------------------------------------------
@@ -64,24 +65,22 @@ def _cac_fwd_kernel(x_ref, tau_ref, s_ref, o_ref, *, bk_sub: int):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...].astype(jnp.float32)  # (bm, bk)
-    tau = tau_ref[...].astype(jnp.float32)  # (bk, bn)
-    s = s_ref[...].astype(jnp.float32)  # (bk, bn)
-    bk = x.shape[1]
+    bk = x_ref.shape[1]
+    win = beat_window(bk)
 
-    def beat(i, acc):
-        # one vectorized beat: bk_sub comparator waves as a single
-        # (bm, bk_sub, bn) broadcast-compare + select, reduced over k_sub
-        k0 = i * bk_sub
-        xs = _slice_k(x, k0, bk_sub, 1)  # (bm, bk_sub)
-        ts = _slice_k(tau, k0, bk_sub, 0)  # (bk_sub, bn)
-        ss = _slice_k(s, k0, bk_sub, 0)
-        cmp = xs[:, :, None] >= ts[None]
-        return acc + jnp.sum(jnp.where(cmp, ss[None], -ss[None]), axis=1)
+    def window(k0, acc):
+        x = x_ref[:, pl.ds(k0, win)].astype(jnp.float32)  # (bm, win)
+        tau = tau_ref[pl.ds(k0, win), :].astype(jnp.float32)  # (win, bn)
+        s = s_ref[pl.ds(k0, win), :].astype(jnp.float32)
+        for sl in beat_slices(win, bk_sub):
+            # one vectorized beat: bk_sub comparator waves as a single
+            # (bm, bk_sub, bn) broadcast-compare + select, reduced over k_sub
+            xs, ts, ss = x[:, sl], tau[sl], s[sl]
+            cmp = xs[:, :, None] >= ts[None]
+            acc = acc + jnp.sum(jnp.where(cmp, ss[None], -ss[None]), axis=1)
+        return acc
 
-    acc = jax.lax.fori_loop(
-        0, bk // bk_sub, beat, jnp.zeros(o_ref.shape, jnp.float32)
-    )
+    acc = fold_windows(bk, win, window, jnp.zeros(o_ref.shape, jnp.float32))
     o_ref[...] += acc.astype(o_ref.dtype)
 
 
@@ -128,22 +127,19 @@ def _cac_train_fwd_kernel(x_ref, w_ref, b_ref, o_ref, *, bk_sub: int):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...].astype(jnp.float32)
-    w = w_ref[...].astype(jnp.float32)
-    b = b_ref[...].astype(jnp.float32)
-    bk = x.shape[1]
+    bk = x_ref.shape[1]
+    win = beat_window(bk)
 
-    def beat(i, acc):
-        k0 = i * bk_sub
-        xs = _slice_k(x, k0, bk_sub, 1)
-        ws = _slice_k(w, k0, bk_sub, 0)
-        bs = _slice_k(b, k0, bk_sub, 0)
-        pre = xs[:, :, None] * ws[None] + bs[None]  # (bm, bk_sub, bn)
-        return acc + jnp.sum(jnp.where(pre >= 0, 1.0, -1.0), axis=1)
+    def window(k0, acc):
+        x = x_ref[:, pl.ds(k0, win)].astype(jnp.float32)
+        w = w_ref[pl.ds(k0, win), :].astype(jnp.float32)
+        b = b_ref[pl.ds(k0, win), :].astype(jnp.float32)
+        for sl in beat_slices(win, bk_sub):
+            pre = x[:, sl][:, :, None] * w[sl][None] + b[sl][None]  # (bm, bk_sub, bn)
+            acc = acc + jnp.sum(jnp.where(pre >= 0, 1.0, -1.0), axis=1)
+        return acc
 
-    acc = jax.lax.fori_loop(
-        0, bk // bk_sub, beat, jnp.zeros(o_ref.shape, jnp.float32)
-    )
+    acc = fold_windows(bk, win, window, jnp.zeros(o_ref.shape, jnp.float32))
     o_ref[...] += acc.astype(o_ref.dtype)
 
 
@@ -184,29 +180,26 @@ def _cac_bwd_dx_kernel(x_ref, w_ref, b_ref, g_ref, dx_ref, *, bk_sub: int):
     def _init():
         dx_ref[...] = jnp.zeros_like(dx_ref)
 
-    x = x_ref[...].astype(jnp.float32)  # (bm, bk)
-    w = w_ref[...].astype(jnp.float32)  # (bk, bn)
-    b = b_ref[...].astype(jnp.float32)
     g = g_ref[...].astype(jnp.float32)  # (bm, bn)
-    bk = x.shape[1]
+    bk = x_ref.shape[1]
+    win = beat_window(bk)
 
-    def beat(i, acc):
-        k0 = i * bk_sub
-        xs = _slice_k(x, k0, bk_sub, 1)
-        ws = _slice_k(w, k0, bk_sub, 0)
-        bs = _slice_k(b, k0, bk_sub, 0)
-        pre = xs[:, :, None] * ws[None] + bs[None]  # (bm, bk_sub, bn)
-        mask = jnp.abs(pre) <= 1.0
-        # effective weight block (the MXU-able Ŵ of DESIGN.md §2)
-        contrib = jnp.sum(
-            jnp.where(mask, g[:, None, :] * ws[None], 0.0), axis=2
-        )  # (bm, bk_sub)
-        return jax.lax.dynamic_update_slice_in_dim(acc, contrib, k0, axis=1)
+    def window(k0, carry):
+        x = x_ref[:, pl.ds(k0, win)].astype(jnp.float32)  # (bm, win)
+        w = w_ref[pl.ds(k0, win), :].astype(jnp.float32)  # (win, bn)
+        b = b_ref[pl.ds(k0, win), :].astype(jnp.float32)
+        cols = []
+        for sl in beat_slices(win, bk_sub):
+            ws = w[sl]
+            pre = x[:, sl][:, :, None] * ws[None] + b[sl][None]  # (bm, bk_sub, bn)
+            mask = jnp.abs(pre) <= 1.0
+            # effective weight block (the MXU-able Ŵ of DESIGN.md §2)
+            cols.append(jnp.sum(jnp.where(mask, g[:, None, :] * ws[None], 0.0),
+                                axis=2))  # (bm, bk_sub)
+        dx_ref[:, pl.ds(k0, win)] += jnp.concatenate(cols, axis=1).astype(dx_ref.dtype)
+        return carry
 
-    acc = jax.lax.fori_loop(
-        0, bk // bk_sub, beat, jnp.zeros(dx_ref.shape, jnp.float32)
-    )
-    dx_ref[...] += acc.astype(dx_ref.dtype)
+    fold_windows(bk, win, window, 0)
 
 
 def cac_train_bwd_dx_call(
@@ -243,32 +236,26 @@ def _cac_bwd_dw_kernel(x_ref, w_ref, b_ref, g_ref, dw_ref, db_ref, *, bk_sub: in
         dw_ref[...] = jnp.zeros_like(dw_ref)
         db_ref[...] = jnp.zeros_like(db_ref)
 
-    x = x_ref[...].astype(jnp.float32)
-    w = w_ref[...].astype(jnp.float32)
-    b = b_ref[...].astype(jnp.float32)
     g = g_ref[...].astype(jnp.float32)
-    bk = x.shape[1]
+    bk = x_ref.shape[1]
+    win = beat_window(bk)
 
-    def beat(i, carry):
-        dw_acc, db_acc = carry
-        k0 = i * bk_sub
-        xs = _slice_k(x, k0, bk_sub, 1)  # (bm, bk_sub)
-        ws = _slice_k(w, k0, bk_sub, 0)  # (bk_sub, bn)
-        bs = _slice_k(b, k0, bk_sub, 0)
-        pre = xs[:, :, None] * ws[None] + bs[None]
-        gm = jnp.where(jnp.abs(pre) <= 1.0, g[:, None, :], 0.0)  # (bm,bk_sub,bn)
-        db_rows = jnp.sum(gm, axis=0)  # (bk_sub, bn)
-        dw_rows = jnp.sum(gm * xs[:, :, None], axis=0)  # (bk_sub, bn)
-        dw_acc = jax.lax.dynamic_update_slice_in_dim(dw_acc, dw_rows, k0, 0)
-        db_acc = jax.lax.dynamic_update_slice_in_dim(db_acc, db_rows, k0, 0)
-        return dw_acc, db_acc
+    def window(k0, carry):
+        x = x_ref[:, pl.ds(k0, win)].astype(jnp.float32)  # (bm, win)
+        w = w_ref[pl.ds(k0, win), :].astype(jnp.float32)  # (win, bn)
+        b = b_ref[pl.ds(k0, win), :].astype(jnp.float32)
+        dw_rows, db_rows = [], []
+        for sl in beat_slices(win, bk_sub):
+            xs = x[:, sl]  # (bm, bk_sub)
+            pre = xs[:, :, None] * w[sl][None] + b[sl][None]
+            gm = jnp.where(jnp.abs(pre) <= 1.0, g[:, None, :], 0.0)  # (bm,bk_sub,bn)
+            db_rows.append(jnp.sum(gm, axis=0))  # (bk_sub, bn)
+            dw_rows.append(jnp.sum(gm * xs[:, :, None], axis=0))
+        dw_ref[pl.ds(k0, win), :] += jnp.concatenate(dw_rows, 0).astype(dw_ref.dtype)
+        db_ref[pl.ds(k0, win), :] += jnp.concatenate(db_rows, 0).astype(db_ref.dtype)
+        return carry
 
-    z = jnp.zeros(dw_ref.shape, jnp.float32)
-    dw_acc, db_acc = jax.lax.fori_loop(
-        0, bk // bk_sub, beat, (z, jnp.zeros_like(z))
-    )
-    dw_ref[...] += dw_acc.astype(dw_ref.dtype)
-    db_ref[...] += db_acc.astype(db_ref.dtype)
+    fold_windows(bk, win, window, 0)
 
 
 def cac_train_bwd_dw_call(
@@ -329,36 +316,28 @@ def _cac_bwd_fused_kernel(
         dw_ref[...] = jnp.zeros_like(dw_ref)
         db_ref[...] = jnp.zeros_like(db_ref)
 
-    x = x_ref[...].astype(jnp.float32)  # (bm, bk)
-    w = w_ref[...].astype(jnp.float32)  # (bk, bn)
-    b = b_ref[...].astype(jnp.float32)
     g = g_ref[...].astype(jnp.float32)  # (bm, bn)
-    bk = x.shape[1]
+    bk = x_ref.shape[1]
+    win = beat_window(bk)
 
-    def beat(t, carry):
-        dx_acc, dw_acc, db_acc = carry
-        k0 = t * bk_sub
-        xs = _slice_k(x, k0, bk_sub, 1)  # (bm, bk_sub)
-        ws = _slice_k(w, k0, bk_sub, 0)  # (bk_sub, bn)
-        bs = _slice_k(b, k0, bk_sub, 0)
-        pre = xs[:, :, None] * ws[None] + bs[None]  # (bm, bk_sub, bn)
-        gm = jnp.where(jnp.abs(pre) <= 1.0, g[:, None, :], 0.0)
-        dx_rows = jnp.sum(gm * ws[None], axis=2)  # (bm, bk_sub)
-        dw_rows = jnp.sum(gm * xs[:, :, None], axis=0)  # (bk_sub, bn)
-        db_rows = jnp.sum(gm, axis=0)  # (bk_sub, bn)
-        dx_acc = jax.lax.dynamic_update_slice_in_dim(dx_acc, dx_rows, k0, 1)
-        dw_acc = jax.lax.dynamic_update_slice_in_dim(dw_acc, dw_rows, k0, 0)
-        db_acc = jax.lax.dynamic_update_slice_in_dim(db_acc, db_rows, k0, 0)
-        return dx_acc, dw_acc, db_acc
+    def window(k0, carry):
+        x = x_ref[:, pl.ds(k0, win)].astype(jnp.float32)  # (bm, win)
+        w = w_ref[pl.ds(k0, win), :].astype(jnp.float32)  # (win, bn)
+        b = b_ref[pl.ds(k0, win), :].astype(jnp.float32)
+        dx_cols, dw_rows, db_rows = [], [], []
+        for sl in beat_slices(win, bk_sub):
+            xs, ws = x[:, sl], w[sl]
+            pre = xs[:, :, None] * ws[None] + b[sl][None]  # (bm, bk_sub, bn)
+            gm = jnp.where(jnp.abs(pre) <= 1.0, g[:, None, :], 0.0)
+            dx_cols.append(jnp.sum(gm * ws[None], axis=2))  # (bm, bk_sub)
+            dw_rows.append(jnp.sum(gm * xs[:, :, None], axis=0))  # (bk_sub, bn)
+            db_rows.append(jnp.sum(gm, axis=0))  # (bk_sub, bn)
+        dx_ref[:, pl.ds(k0, win)] += jnp.concatenate(dx_cols, 1).astype(dx_ref.dtype)
+        dw_ref[pl.ds(k0, win), :] += jnp.concatenate(dw_rows, 0).astype(dw_ref.dtype)
+        db_ref[pl.ds(k0, win), :] += jnp.concatenate(db_rows, 0).astype(db_ref.dtype)
+        return carry
 
-    zx = jnp.zeros(dx_ref.shape, jnp.float32)
-    zw = jnp.zeros(dw_ref.shape, jnp.float32)
-    dx_acc, dw_acc, db_acc = jax.lax.fori_loop(
-        0, bk // bk_sub, beat, (zx, zw, jnp.zeros_like(zw))
-    )
-    dx_ref[...] += dx_acc.astype(dx_ref.dtype)
-    dw_ref[...] += dw_acc.astype(dw_ref.dtype)
-    db_ref[...] += db_acc.astype(db_ref.dtype)
+    fold_windows(bk, win, window, 0)
 
 
 def cac_train_bwd_fused_call(

@@ -9,30 +9,33 @@ table + optional measured cache), and every wrapper — including
 (``block_m`` / ``block_n`` / ``block_k`` / ``block_k_sub``).
 
 ``interpret=None`` auto-selects interpret mode off-TPU, so the same call
-sites run on CPU tests and TPU deployments.
+sites run on CPU tests (the Pallas interpreter) and compile through Mosaic on
+a TPU.
 
-Tensor parallelism: when an active mesh carries a ``model`` axis of size > 1
-(the serving engine enters ``with mesh:`` around its jitted programs), the
-SERVE-path wrappers (``cac_matmul`` / ``bnn_matmul`` / ``bnn_matmul_packed``
-/ ``qnn_matmul``) route the contraction through ``shard_map``
-column-parallel: weights split on their output (N) dim, activations
-replicated, each device running the unmodified kernel on its N-shard. No
-cross-device reduction is introduced, so per-column sums keep the exact
-single-device accumulation order — sharded outputs are bit-identical to the
-unsharded kernel. When N does not divide the model axis the wrapper falls
-back to the pure-XLA reference (kernels/ref.py), which GSPMD partitions
-freely. Training routes keep plain GSPMD partitioning (shard_map + custom
-VJP replication bookkeeping is not worth it for paths the trainer already
-shards well).
+Tensor parallelism: when the active mesh (``jax.set_mesh``, entered by the
+serving engine through ``distributed.constraints.mesh_context``) carries a
+``model`` axis of size > 1, the SERVE-path wrappers (``cac_matmul`` /
+``bnn_matmul`` / ``bnn_matmul_packed`` / ``qnn_matmul``) route the
+contraction through ``jax.shard_map`` column-parallel: weights split on their
+output (N) dim, activations replicated, each device running the unmodified
+kernel on its N-shard. No cross-device reduction is introduced, so per-column
+sums keep the exact single-device accumulation order — sharded outputs are
+bit-identical to the unsharded kernel. When N (or, for ``paged_attention``,
+a head count) does not divide the model axis, the wrapper falls back to the
+pure-XLA reference (kernels/ref.py), which GSPMD partitions freely. That
+fallback is logged once per route and shape, and ``tp_dispatch_log()`` lists
+every decision taken. Training routes keep plain GSPMD partitioning
+(shard_map + custom VJP replication bookkeeping is not worth it for paths the
+trainer already shards well).
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+import logging
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 from . import autotune, ref
@@ -62,7 +65,10 @@ __all__ = [
     "paged_attention",
     "KERNEL_ROUTES",
     "kernel_route",
+    "tp_dispatch_log",
 ]
+
+log = logging.getLogger(__name__)
 
 # Default for the one-pass fused STE backward; the two-call path stays
 # reachable via cac_train_matmul(..., fused_bwd=False) for A/B benchmarking.
@@ -84,8 +90,8 @@ TP_AXIS = "model"
 
 def _tp_mesh():
     """The active mesh when it tensor-parallelizes (model axis > 1), else
-    None. Late-bound off the thread-resource env like nn's ``constrain`` —
-    call sites inside jit pick it up from the caller's ``with mesh:``."""
+    None. Read from ``jax.sharding.get_abstract_mesh()`` — call sites inside
+    jit pick it up from the caller's ``mesh_context`` (jax.set_mesh)."""
     from repro.distributed.constraints import _context_mesh
 
     mesh = _context_mesh()
@@ -94,27 +100,54 @@ def _tp_mesh():
     return mesh
 
 
-def _tp_shard_call(impl, ref_impl, x2: jax.Array, weights: Tuple[jax.Array, ...],
-                   n: int) -> jax.Array:
+# (route, operand shapes) -> "shard_map" | "xla_fallback", one entry per TP
+# dispatch decision (made while tracing, so once per jitted shape)
+_TP_DISPATCH: Dict[Tuple[str, Tuple], str] = {}
+
+
+def _note_tp_dispatch(route: str, shapes: Tuple, taken: str, why: str = "") -> None:
+    key = (route, shapes)
+    if key in _TP_DISPATCH:
+        return
+    _TP_DISPATCH[key] = taken
+    if taken == "xla_fallback":
+        log.warning("tensor-parallel %s at %s runs the XLA reference, not the "
+                    "kernel: %s", route, shapes, why)
+
+
+def tp_dispatch_log() -> Dict[Tuple[str, Tuple], str]:
+    """Every tensor-parallel route decision made so far in this process:
+    ``{(route, operand shapes): "shard_map" | "xla_fallback"}``."""
+    return dict(_TP_DISPATCH)
+
+
+def _tp_shard_call(route: str, impl, ref_impl, x2: jax.Array,
+                   weights: Tuple[jax.Array, ...], n: int) -> jax.Array:
     """Run ``impl(x2, *weights) -> (M, N)`` column-parallel over the model
     axis when a TP mesh is active: every weight operand splits on its last
     (N) dim, ``x2`` is replicated, and the output stays N-sharded for the
     next layer to consume. Each shard runs the unmodified Pallas kernel on
     its (M, K, N/tp) slice — no reduction is split, so the result is
     bit-identical to the single-device kernel. Falls back to ``ref_impl``
-    (pure XLA, GSPMD-partitionable) when N does not divide the axis."""
+    (pure XLA, GSPMD-partitionable) when N does not divide the axis, and
+    logs that fallback once per route and shape."""
     mesh = _tp_mesh()
     if mesh is None:
         return impl(x2, *weights)
-    if n % int(mesh.shape[TP_AXIS]) != 0:
+    tp = int(mesh.shape[TP_AXIS])
+    shapes = (x2.shape,) + tuple(w.shape for w in weights)
+    if n % tp != 0:
+        _note_tp_dispatch(route, shapes, "xla_fallback",
+                          f"N={n} does not divide the model axis ({tp})")
         return ref_impl(x2, *weights)
+    _note_tp_dispatch(route, shapes, "shard_map")
     wspec = PartitionSpec(None, TP_AXIS)
-    fn = shard_map(
+    fn = jax.shard_map(
         impl,
         mesh=mesh,
         in_specs=(PartitionSpec(),) + (wspec,) * len(weights),
         out_specs=PartitionSpec(None, TP_AXIS),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x2, *weights)
 
@@ -175,7 +208,7 @@ def cac_matmul(
     n = tau.shape[1]
     impl = functools.partial(_cac_hw_impl, interpret=_auto_interpret(interpret),
                              blocks=blocks)
-    y = _tp_shard_call(impl, ref.cac_matmul_ref, x2, (tau, s), n)
+    y = _tp_shard_call("cac_hw", impl, ref.cac_matmul_ref, x2, (tau, s), n)
     return y.reshape(lead + (n,))
 
 
@@ -295,7 +328,7 @@ def bnn_matmul(x: jax.Array, w: jax.Array, *, interpret: Optional[bool] = None,
     x2, lead = _flatten(x)
     impl = functools.partial(_bnn_fwd_padded, interpret=_auto_interpret(interpret),
                              blocks=blocks)
-    y = _tp_shard_call(impl, ref.bnn_matmul_ref, x2, (w,), w.shape[1])
+    y = _tp_shard_call("bnn", impl, ref.bnn_matmul_ref, x2, (w,), w.shape[1])
     return y.reshape(lead + (w.shape[1],))
 
 
@@ -340,7 +373,7 @@ def bnn_matmul_packed(x: jax.Array, wp: jax.Array, *,
     n = wp.shape[1]
     impl = functools.partial(_bnn_packed_impl, interpret=_auto_interpret(interpret),
                              blocks=blocks)
-    y = _tp_shard_call(impl, _bnn_packed_ref, x2, (wp,), n)
+    y = _tp_shard_call("bnn_packed", impl, _bnn_packed_ref, x2, (wp,), n)
     return y.reshape(lead + (n,))
 
 
@@ -423,7 +456,7 @@ def qnn_matmul(
     impl = functools.partial(_qnn_impl, x_scale=x_scale,
                              interpret=_auto_interpret(interpret), blocks=blocks)
     ref_impl = lambda xi, wi, ws: ref.qnn_matmul_ref(xi, wi, x_scale, ws)
-    y = _tp_shard_call(impl, ref_impl, x2, (w_int, w_scale), n)
+    y = _tp_shard_call("qnn8", impl, ref_impl, x2, (w_int, w_scale), n)
     return y.reshape(lead + (n,))
 
 
@@ -457,7 +490,8 @@ def paged_attention(
     every head dim split — each device runs the unmodified kernel on its
     heads, bit-identical to the unsharded kernel. When the head counts
     don't divide the axis it falls back to the pure-XLA gather oracle
-    (kernels/ref.py), which GSPMD partitions freely."""
+    (kernels/ref.py), which GSPMD partitions freely, and logs the fallback
+    once per shape (``tp_dispatch_log``)."""
     b, c, hq, d = q.shape
     bs, hkv = k.shape[1], k.shape[2]
     bl = autotune.get_paged_blocks(
@@ -471,21 +505,25 @@ def paged_attention(
         return impl(q, k, v, tables, q_pos,
                     k_scale=k_scale, v_scale=v_scale)
     tp = int(mesh.shape[TP_AXIS])
+    shapes = (q.shape, k.shape, tables.shape)
     if hq % tp or hkv % tp:
+        _note_tp_dispatch("paged_attn", shapes, "xla_fallback",
+                          f"heads {hq}q/{hkv}kv do not divide the model axis ({tp})")
         return ref.paged_attention_ref(q, k, v, tables, q_pos, k_scale, v_scale)
+    _note_tp_dispatch("paged_attn", shapes, "shard_map")
     hspec = PartitionSpec(None, None, TP_AXIS, None)
 
     def sharded(qs, ks, vs, tbl, qp, *sc):
         ksc, vsc = sc if sc else (None, None)
         return impl(qs, ks, vs, tbl, qp, k_scale=ksc, v_scale=vsc)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         sharded,
         mesh=mesh,
         in_specs=(hspec, hspec, hspec, PartitionSpec(), PartitionSpec())
         + (hspec,) * len(scales),
         out_specs=hspec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, k, v, tables, q_pos, *scales)
 

@@ -19,8 +19,14 @@ block table directly instead:
   same element math as nn/attention._dequantize_kv), so the quantized pool
   is never expanded to fp in HBM.
 - whole-block skip: blocks entirely past every query position of the row
-  (``j * bs > max(q_pos)``) skip the compute beat, so decode work scales
-  with each row's *actual* context, not ``max_len``.
+  (``j * bs > max(q_pos)``, the row maximum being a second scalar-prefetch
+  operand) skip the compute beat, so decode work scales with each row's
+  *actual* context, not ``max_len``.
+- GQA without an in-kernel reshape: the wrapper lays q out as ``(B, Hkv,
+  g*C, D)`` and the query positions as ``(B, g*C, 1)``, so every block keeps
+  its last two dims whole (the (8, 128) rule) and each kv head of a step is
+  one 2-D ``(g*C, D) x (bs, D)`` contraction against the pool block's head
+  slice ``k_ref[0, :, h, :]``.
 
 One kernel serves both paged call sites: single-token decode is ``C = 1``
 with ``q_pos = position`` and chunked prefill is ``C = chunk`` with per-token
@@ -28,7 +34,10 @@ logical positions (intra-chunk causality falls out of the same mask).
 
 Numerics: fp32 score/softmax math like dot_attention, but blockwise
 accumulation — outputs are within float rounding (~1e-6) of the gather
-oracle, not bit-equal; the serving tests pin token-for-token parity.
+oracle, not bit-equal; the serving tests pin token-for-token parity. On a
+TPU the dots follow the matmul precision in effect: at the default, Mosaic
+runs f32 dots as bf16 passes (a few 1e-3 off a "highest" oracle on a v5e),
+at "highest" as f32 (~1e-6); chip_smoke.py checks both.
 
 TPU note: block_size and head_dim below the (8, 128) f32 tile pad in VMEM;
 the heuristic in kernels/autotune.py sizes ``block_h`` so a step's working
@@ -49,13 +58,15 @@ __all__ = ["paged_attn_kernel_call"]
 NEG_INF = -1e30  # matches nn/attention.py masking
 
 
-def _paged_attn_kernel(tbl_ref, qpos_ref, q_ref, k_ref, v_ref, *rest,
-                       bs: int, g: int, quantized: bool):
+def _paged_attn_kernel(tbl_ref, qmax_ref, qpos_ref, q_ref, k_ref, v_ref, *rest,
+                       bs: int, quantized: bool):
     if quantized:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
-    j = pl.program_id(2)
+    del tbl_ref  # consumed by the pool index maps
+    row, j = pl.program_id(0), pl.program_id(2)
+    bh, rows, d = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
 
     @pl.when(j == 0)
     def _init():
@@ -63,40 +74,35 @@ def _paged_attn_kernel(tbl_ref, qpos_ref, q_ref, k_ref, v_ref, *rest,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    c, _, d = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
-    bh = k_ref.shape[2]
-    qp = qpos_ref[0]  # (C,) int32 logical positions of the query tokens
-
     # Whole-block skip: every position of block j is causally past every
     # query of this row. State carries; the flush below still runs.
-    @pl.when(j * bs <= jnp.max(qp))
+    @pl.when(j * bs <= qmax_ref[row])
     def _update():
-        q = q_ref[0].astype(jnp.float32).reshape(c, bh, g, d)
-        k = k_ref[0].astype(jnp.float32)  # (bs, bh, D)
-        v = v_ref[0].astype(jnp.float32)
-        if quantized:
-            k = k * ks_ref[0]
-            v = v * vs_ref[0]
-        s = jnp.einsum("chgd,thd->chgt", q, k,
-                       preferred_element_type=jnp.float32)
-        s = s / jnp.sqrt(jnp.float32(d))
-        kvp = j * bs + jax.lax.broadcasted_iota(jnp.int32, (c, bs), 1)
-        mask = kvp <= qp[:, None]  # (C, bs): causal + valid_len in one
-        s = jnp.where(mask[:, None, None, :], s, NEG_INF)
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[..., None])  # masked lanes underflow to 0
-        m_ref[...] = m_new
-        l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1)
-        acc_ref[...] = alpha[..., None] * acc_ref[...] + jnp.einsum(
-            "chgt,thd->chgd", p, v, preferred_element_type=jnp.float32)
+        kvp = j * bs + jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 1)
+        mask = kvp <= qpos_ref[0]  # (rows, bs): causal + valid_len in one
+        for h in range(bh):  # static: one kv head and its query group
+            q = q_ref[0, h].astype(jnp.float32)  # (g*C, D)
+            k = k_ref[0, :, h, :].astype(jnp.float32)  # (bs, D)
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            if quantized:
+                k = k * ks_ref[0, :, h, :]
+                v = v * vs_ref[0, :, h, :]
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(mask, s / jnp.sqrt(jnp.float32(d)), NEG_INF)
+            m_prev, l_prev = m_ref[h], l_ref[h]  # (g*C, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)  # masked lanes underflow to 0
+            m_ref[h] = m_new
+            l_ref[h] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[h] = alpha * acc_ref[h] + jnp.dot(
+                p, v, preferred_element_type=jnp.float32)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _flush():
         # kv position 0 is always <= q_pos, so l > 0 on every row
-        out = acc_ref[...] / l_ref[...][..., None]
-        o_ref[0] = out.reshape(c, bh * g, d).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
 def paged_attn_kernel_call(
@@ -126,44 +132,53 @@ def paged_attn_kernel_call(
     bh = max(1, min(int(block_h or hkv), hkv))
     while hkv % bh:
         bh -= 1
-    hgb = bh * g
     t = tables.shape[1]
+    rows = g * c
+    q_pos = q_pos.astype(jnp.int32)
+    # Query rows grouped under their kv head, row r = (r // C)-th head of
+    # the group at chunk token r % C: (B, Hkv, g*C, D). Every kernel block
+    # then keeps its last two dims whole, and the GQA grouping is a plain
+    # 2-D (g*C, D) x (bs, D) contraction per kv head.
+    qg = q.reshape(b, c, hkv, g, d).transpose(0, 2, 3, 1, 4).reshape(b, hkv, rows, d)
+    qp_rows = jnp.broadcast_to(q_pos[:, None, :], (b, g, c)).reshape(b, rows, 1)
+    q_max = jnp.max(q_pos, axis=1)  # (B,) whole-block skip bound
 
-    def hmap(bb, hh, jj, tbl):  # q/out: row bb, kv-head group hh
-        return (bb, 0, hh, 0)
+    def hmap(bb, hh, jj, tbl, qm):  # q/out: row bb, kv-head group hh
+        return (bb, hh, 0, 0)
 
-    def pmap(bb, hh, jj, tbl):  # q_pos: row bb
-        return (bb, 0)
+    def pmap(bb, hh, jj, tbl, qm):  # query positions: row bb
+        return (bb, 0, 0)
 
-    def kmap(bb, hh, jj, tbl):  # pool: the table names the physical block
+    def kmap(bb, hh, jj, tbl, qm):  # pool: the table names the physical block
         return (tbl[bb, jj], 0, hh, 0)
 
     in_specs = [
-        pl.BlockSpec((1, c), pmap),
-        pl.BlockSpec((1, c, hgb, d), hmap),
+        pl.BlockSpec((1, rows, 1), pmap),
+        pl.BlockSpec((1, bh, rows, d), hmap),
         pl.BlockSpec((1, bs, bh, d), kmap),
         pl.BlockSpec((1, bs, bh, d), kmap),
     ]
-    args = [tables.astype(jnp.int32), q_pos.astype(jnp.int32), q, k, v]
+    args = [tables.astype(jnp.int32), q_max, qp_rows, qg, k, v]
     if quantized:
         in_specs += [pl.BlockSpec((1, bs, bh, 1), kmap),
                      pl.BlockSpec((1, bs, bh, 1), kmap)]
         args += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, hkv // bh, t),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, c, hgb, d), hmap),
+        out_specs=pl.BlockSpec((1, bh, rows, d), hmap),
         scratch_shapes=[
-            pltpu.VMEM((c, bh, g), jnp.float32),  # running max
-            pltpu.VMEM((c, bh, g), jnp.float32),  # running denominator
-            pltpu.VMEM((c, bh, g, d), jnp.float32),  # output accumulator
+            pltpu.VMEM((bh, rows, 1), jnp.float32),  # running max
+            pltpu.VMEM((bh, rows, 1), jnp.float32),  # running denominator
+            pltpu.VMEM((bh, rows, d), jnp.float32),  # output accumulator
         ],
     )
-    kernel = functools.partial(_paged_attn_kernel, bs=bs, g=g, quantized=quantized)
-    return pl.pallas_call(
+    kernel = functools.partial(_paged_attn_kernel, bs=bs, quantized=quantized)
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, c, hq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
         interpret=interpret,
     )(*args)
+    return out.reshape(b, hkv, g, c, d).transpose(0, 3, 1, 2, 4).reshape(b, c, hq, d)

@@ -6,8 +6,9 @@ comparison rows in the Table III analogue.
 
 Same schedule treatment as the CAC stack (DESIGN.md §2/§3): grid
 (M/bm, N/bn, K/bk) with the k-grid innermost accumulating into a VMEM fp32
-block, and a ``bk_sub`` beat loop inside each block so only an
-(bm, bk_sub) x (bk_sub, bn) operand pair is widened to int32 per beat.
+block. Inside a block the K extent is read from the refs in tile-aligned
+windows (kernels/beats.py) and contracted ``bk_sub`` rows per beat as int8
+operands with an int32 MXU accumulator.
 Blocks come from kernels/autotune.py (path ``qnn8``) and every caller
 accepts explicit overrides.
 """
@@ -21,6 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .autotune import pick_block_k_sub
+from .beats import beat_slices, beat_window, fold_windows
 
 __all__ = ["qnn_matmul_kernel_call"]
 
@@ -30,19 +32,17 @@ def _qnn_kernel(x_ref, w_ref, scale_ref, o_ref, *, bk_sub: int, n_k: int):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...]  # (bm, bk) int8
-    w = w_ref[...]  # (bk, bn) int8
-    bk = x.shape[1]
+    bk = x_ref.shape[1]
+    win = beat_window(bk)
 
-    def beat(i, acc):
-        k0 = i * bk_sub
-        xs = jax.lax.dynamic_slice_in_dim(x, k0, bk_sub, 1).astype(jnp.int32)
-        ws = jax.lax.dynamic_slice_in_dim(w, k0, bk_sub, 0).astype(jnp.int32)
-        return acc + jnp.dot(xs, ws, preferred_element_type=jnp.int32)
+    def window(k0, acc):
+        x = x_ref[:, pl.ds(k0, win)]  # (bm, win) int8
+        w = w_ref[pl.ds(k0, win), :]  # (win, bn) int8
+        for sl in beat_slices(win, bk_sub):
+            acc = acc + jnp.dot(x[:, sl], w[sl], preferred_element_type=jnp.int32)
+        return acc
 
-    acc = jax.lax.fori_loop(
-        0, bk // bk_sub, beat, jnp.zeros(o_ref.shape, jnp.int32)
-    )
+    acc = fold_windows(bk, win, window, jnp.zeros(o_ref.shape, jnp.int32))
     o_ref[...] += acc.astype(jnp.float32)
 
     @pl.when(pl.program_id(2) == n_k - 1)

@@ -38,6 +38,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.configs import ARCH_NAMES, SHAPES, applicable_shapes, get_config
 from repro.data.lm import LMDataConfig, lm_batch_specs
+from repro.distributed.constraints import mesh_context
 from repro.distributed.sharding import (
     FSDP_RULES,
     ShardingRules,
@@ -254,7 +255,7 @@ def run_cell(arch: str, shape_name: str, mesh, mesh_name: str, *, mode: str = "b
     try:
         fn, args, meta = build_cell(arch, shape_name, mesh, mode=mode, **kw)
         rec.update(meta)
-        with mesh:
+        with mesh_context(mesh):
             lowered = fn.lower(*args)
             t_lower = time.time()
             compiled = lowered.compile()

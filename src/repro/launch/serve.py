@@ -35,6 +35,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCH_NAMES, get_config, get_smoke
+from repro.launch.cache import use_persistent_compile_cache
 from repro.models import build_model
 from repro.nn.module import param_bytes, unbox
 from repro.obs import MetricsRegistry, Tracer, profile_trace, set_tracer
@@ -119,6 +120,7 @@ def main(argv=None) -> int:
     ap.add_argument("--profile-sample", type=int, default=0,
                     help="phase-time every Nth scheduler tick (0 = off)")
     args = ap.parse_args(argv)
+    use_persistent_compile_cache()
     mesh = build_serve_mesh(args.tp, args.mesh_shape)
 
     tracer = Tracer(capacity=args.trace_capacity) if args.trace_out else None

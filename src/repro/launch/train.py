@@ -19,6 +19,7 @@ from jax.sharding import Mesh
 
 from repro.configs import ARCH_NAMES, get_config, get_smoke
 from repro.distributed.sharding import FSDP_RULES, LOGICAL_RULES, ShardingRules
+from repro.launch.cache import use_persistent_compile_cache
 from repro.optim.adamw import OptimizerSpec
 from repro.train.trainer import TrainConfig, Trainer
 
@@ -46,6 +47,7 @@ def main(argv=None) -> int:
     ap.add_argument("--mesh", default="auto", choices=("auto", "prod"))
     ap.add_argument("--fsdp", action="store_true")
     args = ap.parse_args(argv)
+    use_persistent_compile_cache()
 
     getter = get_smoke if args.smoke else get_config
     arch = getter(args.arch, compute_mode=args.mode)

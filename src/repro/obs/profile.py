@@ -166,12 +166,10 @@ NULL_TIMER = NullStepTimer()
 
 def profile_trace(log_dir: Optional[str]):
     """``jax.profiler.trace`` context for a serve window (``--profile-dir``).
-    Falsy dir -> null context; an unavailable profiler degrades gracefully."""
+    Falsy dir -> null context. A profiler that cannot start raises: a run
+    asked for a profile does not go on without one."""
     if not log_dir:
         return contextlib.nullcontext()
-    try:
-        import jax
+    import jax
 
-        return jax.profiler.trace(log_dir)
-    except Exception:  # profiler backend missing in this build
-        return contextlib.nullcontext()
+    return jax.profiler.trace(log_dir)

@@ -27,13 +27,13 @@ sharded inputs.
 """
 from __future__ import annotations
 
-import contextlib
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.distributed.constraints import mesh_context
 from repro.obs.trace import NULL_TRACER
 
 __all__ = ["BucketedPrefill", "ChunkedPrefill", "bucket_for"]
@@ -92,7 +92,7 @@ class BucketedPrefill:
     def _mesh_ctx(self):
         """Activate the mesh while tracing/running so in-model constraints
         and the TP kernel routes see it; identity off-mesh."""
-        return self.mesh if self.mesh is not None else contextlib.nullcontext()
+        return mesh_context(self.mesh)
 
     @property
     def compiled_buckets(self) -> List[Tuple[int, int]]:
@@ -216,7 +216,7 @@ class ChunkedPrefill:
             )
 
     def _mesh_ctx(self):
-        return self.mesh if self.mesh is not None else contextlib.nullcontext()
+        return mesh_context(self.mesh)
 
     def fn(self) -> Callable:
         if self._fn is None:

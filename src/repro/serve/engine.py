@@ -57,7 +57,6 @@ token-for-token identical to the single-device engine (DESIGN.md §5).
 """
 from __future__ import annotations
 
-import contextlib
 from typing import Dict, List, Optional, Sequence
 
 import jax
@@ -65,6 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.convert import tree_to_serve
+from repro.distributed.constraints import mesh_context
 from repro.models.base import ArchConfig, ModelAPI
 from repro.serve.scheduler import (
     PagedSlotScheduler,
@@ -204,7 +204,7 @@ class ServeEngine:
         self.queue: List[Request] = []
 
     def _mesh_ctx(self):
-        return self.mesh if self.mesh is not None else contextlib.nullcontext()
+        return mesh_context(self.mesh)
 
     @classmethod
     def from_trained(

@@ -26,7 +26,6 @@ never split) and still compiles exactly once.
 """
 from __future__ import annotations
 
-import contextlib
 import heapq
 from functools import partial
 from typing import List, Optional
@@ -34,6 +33,7 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.distributed.constraints import mesh_context
 from repro.models.base import KVCacheLayout, kv_cache_layout
 
 __all__ = ["KVSlotManager"]
@@ -138,8 +138,7 @@ class KVSlotManager:
         pl = kv_cache_layout(prefill_cache)
         if pl.n_slots != 1 or pl.max_len != self.max_len:
             raise ValueError(f"prefill cache layout {pl} does not match pool {self.layout}")
-        ctx = self.mesh if self.mesh is not None else contextlib.nullcontext()
-        with ctx:
+        with mesh_context(self.mesh):
             self.cache = self._splice_fn(prefill_cache)(
                 self.cache, prefill_cache, jnp.asarray(slot, jnp.int32)
             )

@@ -51,7 +51,6 @@ whole-prompt prefill attends raw fp keys and only quantizes what it stores.
 """
 from __future__ import annotations
 
-import contextlib
 import hashlib
 import heapq
 from collections import OrderedDict
@@ -61,6 +60,7 @@ from typing import Dict, List, Optional
 import jax
 import numpy as np
 
+from repro.distributed.constraints import mesh_context
 from repro.models.base import PagedKVLayout, paged_kv_layout
 from repro.obs.trace import NULL_TRACER
 
@@ -362,8 +362,7 @@ class PagedKVManager:
             if not self._free_set and not self._lru:
                 raise RuntimeError("copy-on-write with no free or evictable block")
             nb = self._alloc_block()
-            ctx = self.mesh if self.mesh is not None else contextlib.nullcontext()
-            with ctx:
+            with mesh_context(self.mesh):
                 self.cache = self._copy_fn()(
                     self.cache, np.int32(b), np.int32(nb)
                 )

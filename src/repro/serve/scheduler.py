@@ -50,7 +50,6 @@ is built). Speculative + ``mesh`` is not implemented.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import time
 from typing import Callable, List, Optional, Tuple
@@ -59,6 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.distributed.constraints import mesh_context
 from repro.models.base import ArchConfig, ModelAPI
 from repro.obs.profile import NULL_TIMER, StepTimer
 from repro.obs.trace import Tracer, get_tracer
@@ -188,7 +188,7 @@ class SlotScheduler:
             self._spec_fn = self._build_spec_fn()
 
     def _mesh_ctx(self):
-        return self.mesh if self.mesh is not None else contextlib.nullcontext()
+        return mesh_context(self.mesh)
 
     def _metric_labels(self) -> dict:
         return dict(mode=self.arch.compute_mode, engine=self.engine_name,

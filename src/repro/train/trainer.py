@@ -22,6 +22,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.checkpoint import CheckpointManager, latest_step
 from repro.data.lm import LMDataConfig, lm_batch
+from repro.distributed.constraints import mesh_context
 from repro.distributed.sharding import (
     ShardingRules,
     batch_sharding,
@@ -144,7 +145,7 @@ class Trainer:
     def init_or_restore(self, key=None):
         key = key if key is not None else jax.random.PRNGKey(42)
         start = 0
-        with self.mesh:
+        with mesh_context(self.mesh):
             params = self.jit_init(key)
             opt_state = self.jit_opt_init(params)
         if self.ckpt and latest_step(self.cfg.ckpt_dir) is not None:
@@ -160,7 +161,7 @@ class Trainer:
         if params is None:
             params, opt_state, start_step = self.init_or_restore()
         t0 = time.time()
-        with self.mesh:
+        with mesh_context(self.mesh):
             for step in range(start_step, self.cfg.steps):
                 if self.fail_at_step is not None and step == self.fail_at_step:
                     raise SimulatedFailure(f"injected failure at step {step}")

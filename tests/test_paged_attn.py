@@ -342,3 +342,33 @@ def test_fused_route_tp2_token_identical():
     print("FUSED_TP2_OK")
     """)
     assert "FUSED_TP2_OK" in out
+
+
+def test_tp_route_choice_is_logged_once_per_shape(caplog, monkeypatch):
+    """Under a model-axis mesh the kernel runs per head shard when the head
+    counts divide the axis, and otherwise falls back to the XLA oracle —
+    visibly: one warning per (route, shape), and ``tp_dispatch_log`` names
+    the route each call took."""
+    # the dispatch log is process-global: start from an empty one so that
+    # an earlier trace of the same shapes cannot swallow the warning
+    monkeypatch.setattr(ops, "_TP_DISPATCH", {})
+    mesh = jax.sharding.AbstractMesh((2,), ("model",))
+    f32, i32 = jnp.float32, jnp.int32
+    S = jax.ShapeDtypeStruct
+
+    def trace(hq, hkv):
+        args = (S((2, 1, hq, 16), f32), S((5, 8, hkv, 16), f32),
+                S((5, 8, hkv, 16), f32), S((2, 2), i32), S((2, 1), i32))
+        with jax.sharding.use_abstract_mesh(mesh):
+            out = jax.eval_shape(ops.paged_attention, *args)
+        assert out.shape == (2, 1, hq, 16)
+        return ("paged_attn", (args[0].shape, args[1].shape, args[3].shape))
+
+    with caplog.at_level("WARNING", logger="repro.kernels.ops"):
+        odd = trace(3, 1)  # 3q/1kv heads do not divide model=2
+        trace(3, 1)
+        even = trace(4, 2)
+    log = ops.tp_dispatch_log()
+    assert log[odd] == "xla_fallback" and log[even] == "shard_map"
+    warned = [r for r in caplog.records if "runs the XLA reference" in r.getMessage()]
+    assert len(warned) == 1

@@ -158,9 +158,10 @@ def test_paged_quantized_kv_runs_and_is_deterministic(lm):
 def test_chunked_prefill_logits_exact(lm):
     """Appending a prompt through the (1, chunk) program yields the same
     last-token logits as the dense continuous engine's bucketed prefill.
-    The gather route is bit-for-bit — the oracle that matters for engine
-    parity. The fused block-walk route (the default) reorders the softmax
-    reduction online, so it lands ~2e-7 off and argmax absorbs it, as the
+    The gather route computes the same math as the bucketed prefill in a
+    different XLA program, so it agrees to float rounding. The fused
+    block-walk route (the default) reorders the softmax reduction online,
+    so it lands ~2e-7 off; in both cases argmax absorbs it, as the
     end-to-end token-parity tests assert. (The *unpadded* whole-prompt
     prefill differs from BOTH padded paths by ~2e-7 at some lengths:
     XLA's reduction order is shape-dependent.)"""
@@ -180,7 +181,14 @@ def test_chunked_prefill_logits_exact(lm):
         got, kv.cache, n_chunks = cp(params, kv.cache, kv.tables[slot], prompt, 0)
         assert n_chunks == -(-plen // 8)
         assert cp.misses == 1 and cp.hits == n_chunks - 1  # one program total
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        # Two different XLA programs (a (1, chunk) paged step vs a (1,
+        # bucket) dense prefill) may sum the same terms in a different
+        # order: logits of order 1 then differ by a few f32 ulps (2.7e-7
+        # seen with XLA-CPU in jax 0.9). 2e-6 is ~16 ulps at magnitude 1 —
+        # reduction-order noise passes, a wrong mask or position does not.
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-6, rtol=2e-6)
+        assert int(np.argmax(got)) == int(np.argmax(want))
 
         kv = PagedKVManager(api, n_slots=1, max_len=32, block_size=8)
         slot = kv.alloc_slot()
