@@ -1,0 +1,43 @@
+"""Kernels: the fused paged-attention kernel's share of its roofline over
+the traced window, in percent. Operations and bytes come from the live
+context of every call in the window (``bench/kernels/paged_attn.py``: the
+decode ticks' active rows and the prefill chunks' real tokens, one call per
+layer); the time is the summed device time of the kernel's ops in the
+trace. The program gives the Pallas kernel no name, so its ops are found as
+the ``tpu_custom_call`` ops that take one layer of the KV pool
+(``[<blocks>,<block>,<kv heads>,<head dim>]``): the only Pallas kernel on
+this path. The least time is the larger of operations over the chip's
+bf16 peak, its one published matmul peak (at "highest" the kernel's f32
+dots take several bf16 passes, so this bound is the lower one), and bytes
+over HBM bandwidth."""
+import sys
+
+from bench import model, peaks
+from bench.kernels import paged_attn
+
+
+def read(run):
+    cfg, w = run.cfg, run.rec.trace_window
+    hq, hkv, d = cfg["num_attention_heads"], cfg["num_key_value_heads"], model.head_dim(cfg)
+    pool = f",{cfg['serve']['kv_block_size']},{hkv},{d}]"
+    secs, n = run.trace.kernel_s(lambda name: 'custom_call_target="tpu_custom_call"' in name
+                                 and pool in name)
+    if not n:
+        return None
+    layers = cfg["num_hidden_layers"]
+    inside = lambda r: r.t0 >= w.open and r.t1 <= w.close
+    ops = bts = 0.0
+    for t in run.rec.ticks:
+        if inside(t):
+            o, b = paged_attn.decode_call(t.positions, hq, hkv, d)
+            ops, bts = ops + layers * o, bts + layers * b
+    for p in run.rec.prefills:
+        if inside(p):
+            for s in range(p.cached, p.prompt_len, p.chunk):
+                o, b = paged_attn.chunk_call(s, min(p.chunk, p.prompt_len - s), hq, hkv, d)
+                ops, bts = ops + layers * o, bts + layers * b
+    pk = peaks.peaks(run.device_kind)
+    t_ops, t_bytes = ops / pk["bf16_flops_per_s"], bts / pk["hbm_bytes_per_s"]
+    print(f"paged_attn: {n} calls, {secs} s on the device, {ops} ops, {bts} bytes; "
+          f"bound by {'compute' if t_ops >= t_bytes else 'HBM bandwidth'}", file=sys.stderr)
+    return 100.0 * max(t_ops, t_bytes) / secs
