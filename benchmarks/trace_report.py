@@ -93,7 +93,7 @@ def report(data: Dict) -> None:
     for s in spans:
         track = s.get("track", "")
         fam = "slots" if track.startswith("slot") else track
-        # profiler phases reuse lifecycle names (decode); keep them distinct
+        # the same name on different track families stays distinct
         by_name[f"{s['name']} [{fam}]"].append(float(s.get("dur", 0.0)))
     if by_name:
         print("\nper-phase span totals:")

@@ -180,5 +180,6 @@ def paged_attn_kernel_call(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
         interpret=interpret,
+        name="paged_attention",  # the kernel's name in a profiler trace
     )(*args)
     return out.reshape(b, hkv, g, c, d).transpose(0, 3, 1, 2, 4).reshape(b, c, hq, d)

@@ -11,14 +11,15 @@ the same open-loop load shape as benchmarks/serving_bench.py.
 
 Observability (DESIGN.md §8, all opt-in):
 
-- ``--trace-out trace.jsonl`` records the run's request-lifecycle spans and
-  paged-path events to JSONL (plus ``trace.perfetto.json`` next to it,
-  loadable at ui.perfetto.dev); validate/report with
+- ``--trace-out trace.jsonl`` records the run's request-lifecycle spans,
+  the serve loop's ``serve.*`` spans and paged-path events to JSONL (plus
+  ``trace.perfetto.json`` next to it, loadable at ui.perfetto.dev), and
+  prints the ``serve.*`` spans' self times; validate/report with
   ``benchmarks/trace_report.py``.
 - ``--metrics-out metrics.prom`` dumps the metrics registry in Prometheus
   text format (``.json`` suffix -> JSON snapshot).
-- ``--profile-sample N`` phase-times every Nth scheduler tick;
-  ``--profile-dir DIR`` wraps the drain in ``jax.profiler.trace``.
+- ``--profile-dir DIR`` wraps the drain in ``jax.profiler.trace``; the
+  ``serve.*`` spans appear on its host plane.
 
 ``--tp N`` serves tensor-parallel on a (n_devices/N, N) data x model mesh
 built from the local devices (``--mesh-shape d,m`` pins an explicit shape):
@@ -38,7 +39,7 @@ from repro.configs import ARCH_NAMES, get_config, get_smoke
 from repro.launch.cache import use_persistent_compile_cache
 from repro.models import build_model
 from repro.nn.module import param_bytes, unbox
-from repro.obs import MetricsRegistry, Tracer, profile_trace, set_tracer
+from repro.obs import MetricsRegistry, Tracer, profile_trace, self_times, set_tracer
 from repro.serve.engine import Request, ServeEngine
 from repro.serve.scheduler import replay_arrivals
 
@@ -117,8 +118,6 @@ def main(argv=None) -> int:
                          "JSON snapshot when the path ends in .json")
     ap.add_argument("--profile-dir", default="",
                     help="wrap the drain in jax.profiler.trace writing here")
-    ap.add_argument("--profile-sample", type=int, default=0,
-                    help="phase-time every Nth scheduler tick (0 = off)")
     args = ap.parse_args(argv)
     use_persistent_compile_cache()
     mesh = build_serve_mesh(args.tp, args.mesh_shape)
@@ -141,8 +140,7 @@ def main(argv=None) -> int:
                   kv_n_blocks=args.kv_n_blocks or None,
                   prefix_cache=args.prefix_cache,
                   prefill_chunk=args.prefill_chunk, mesh=mesh,
-                  tracer=tracer, registry=registry,
-                  profile_sample=args.profile_sample)
+                  tracer=tracer, registry=registry)
     if args.spec_draft:
         # speculative decoding needs the trained float tree so the SAME
         # weights can be converted through both the target backend and the
@@ -207,13 +205,13 @@ def main(argv=None) -> int:
             print(f"[serve] spec: rounds={m['spec_rounds']} "
                   f"accept rate={m['spec_accept_rate']:.2f} "
                   f"tokens/round={m['spec_tokens_per_round']:.2f}")
-    if eng.profiler is not None and eng.profiler.sampled_ticks:
-        ps = eng.profiler.summary()
-        split = " ".join(f"{k}={v['fraction']:.0%}"
-                         for k, v in ps["phases"].items())
-        print(f"[serve] profile: {ps['sampled_ticks']}/{ps['ticks']} ticks "
-              f"sampled; {split}")
     if tracer is not None:
+        own = self_times(tracer.records)
+        total = sum(own.values())
+        if total > 0:
+            split = " ".join(f"{k}={v * 1e3:.1f}ms({v / total:.0%})"
+                             for k, v in sorted(own.items(), key=lambda kv: -kv[1]))
+            print(f"[serve] profile (serve.* span self times): {split}")
         summary = (eng.metrics.summary() if eng.metrics is not None else None)
         requests = ([r.metrics.to_dict() for r in done
                      if r.metrics is not None] or None)

@@ -1,7 +1,7 @@
 """repro.obs — serving observability: structured tracing with Perfetto
 export (trace), a typed metrics registry with Prometheus exposition
-(registry), and sampled step-timer / jax.profiler hooks (profile)."""
-from .profile import NULL_TIMER, NullStepTimer, StepTimer, profile_trace
+(registry), and a jax.profiler hook (profile)."""
+from .profile import profile_trace
 from .registry import (
     DEFAULT_BUCKETS,
     Counter,
@@ -17,6 +17,7 @@ from .trace import (
     Tracer,
     get_tracer,
     records_to_perfetto,
+    self_times,
     set_tracer,
 )
 
@@ -26,16 +27,14 @@ __all__ = [
     "Gauge",
     "Histogram",
     "MetricsRegistry",
-    "NULL_TIMER",
     "NULL_TRACER",
-    "NullStepTimer",
     "NullTracer",
-    "StepTimer",
     "TRACE_SCHEMA_VERSION",
     "TraceRecord",
     "Tracer",
     "get_tracer",
     "profile_trace",
     "records_to_perfetto",
+    "self_times",
     "set_tracer",
 ]

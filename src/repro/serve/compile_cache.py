@@ -227,8 +227,7 @@ class ChunkedPrefill:
         return self._fn
 
     def __call__(self, params, cache, table_row: np.ndarray, prompt: np.ndarray,
-                 cached_len: int = 0, *, trace_track: Optional[str] = None,
-                 rid: Optional[int] = None):
+                 cached_len: int = 0, *, rid: Optional[int] = None):
         """Append ``prompt[cached_len:]`` to the pool chunk by chunk.
 
         Returns ``(last_logits (1,1,V), cache, n_chunks)`` where
@@ -236,10 +235,10 @@ class ChunkedPrefill:
         bit-identical to the bucketed whole-prompt prefill the dense
         continuous engine admits with (tests/test_paged_kv.py).
 
-        With ``trace_track`` (the admitting slot's track) each chunk call
-        becomes a ``prefill_chunk`` span nested inside the scheduler's
-        ``prefill`` span; the one-ever program build additionally emits a
-        ``compile`` span on the scheduler track.
+        Each chunk's staging, upload and dispatch is one
+        ``serve.prefill_chunk`` span (``rid``, ``chunk``) on the scheduler
+        track; the one-ever program build additionally records a
+        ``compile`` span with the same stamps.
         """
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         plen = len(prompt)
@@ -249,29 +248,24 @@ class ChunkedPrefill:
             raise ValueError(f"cached_len {cached_len} outside [0, {plen - 1}]")
         table = jnp.asarray(table_row, jnp.int32).reshape(1, -1)
         tracer = self.tracer
-        trace = tracer.enabled and trace_track is not None
         logits = None
         n_chunks = 0
         start = cached_len
         while start < plen:
             end = min(start + self.chunk, plen)
-            toks = np.zeros((1, self.chunk), np.int32)
-            toks[0, : end - start] = prompt[start:end]
-            last = (plen - 1 - start) if end == plen else (self.chunk - 1)
             misses0 = self.misses
-            t0 = tracer.clock() if trace else 0.0
-            with self._mesh_ctx():
-                logits, cache = self.fn()(
-                    params, cache, jnp.asarray(toks), table,
-                    jnp.asarray([start], jnp.int32), jnp.asarray([last], jnp.int32),
-                )
-            if trace:
-                t1 = tracer.clock()
-                tracer.add_span("prefill_chunk", trace_track, t0, t1,
-                                rid=rid, chunk=n_chunks, start=start, end=end)
-                if self.misses > misses0:
-                    tracer.add_span("compile", "scheduler", t0, t1,
-                                    kind="prefill_chunk", chunk_size=self.chunk)
+            with tracer.span("serve.prefill_chunk", rid=rid, chunk=n_chunks) as span:
+                toks = np.zeros((1, self.chunk), np.int32)
+                toks[0, : end - start] = prompt[start:end]
+                last = (plen - 1 - start) if end == plen else (self.chunk - 1)
+                with self._mesh_ctx():
+                    logits, cache = self.fn()(
+                        params, cache, jnp.asarray(toks), table,
+                        jnp.asarray([start], jnp.int32), jnp.asarray([last], jnp.int32),
+                    )
+            if tracer.enabled and self.misses > misses0:
+                tracer.add_span("compile", "scheduler", span.t0, span.t1,
+                                kind="prefill_chunk", chunk_size=self.chunk)
             n_chunks += 1
             start = end
         return logits, cache, n_chunks

@@ -103,7 +103,6 @@ class ServeEngine:
         rules=None,
         tracer=None,
         registry=None,
-        profile_sample: int = 0,
         spec_draft=None,
         spec_k: int = 1,
     ):
@@ -121,14 +120,6 @@ class ServeEngine:
         self.engine = engine
         self.tracer = tracer
         self.registry = registry
-        # opt-in sampled step timer: every Nth decode tick is phase-timed
-        # with a device sync (0 = off -> allocation-free NullStepTimer)
-        profiler = None
-        if profile_sample and profile_sample > 0:
-            from repro.obs.profile import StepTimer
-
-            profiler = StepTimer(profile_sample, tracer=tracer)
-        self.profiler = profiler
         # speculative decoding (DESIGN.md §10): spec_draft is a prebuilt
         # (draft_api, draft_params, draft_arch) triple — ``from_trained``
         # resolves string presets ("bnn"/"qnn8"/"bika"/"dense"/"small")
@@ -138,7 +129,7 @@ class ServeEngine:
                 f"spec_draft needs a slot-scheduler engine (continuous/paged); "
                 f"got engine={engine!r}"
             )
-        obs_kw = dict(tracer=tracer, registry=registry, profiler=profiler,
+        obs_kw = dict(tracer=tracer, registry=registry,
                       spec_draft=spec_draft, spec_k=spec_k)
         self.scheduler: Optional[SlotScheduler] = None
         if engine == "paged":

@@ -308,41 +308,44 @@ class PagedKVManager:
         (refcount bump), reserve fresh blocks for the rest of the span.
         Returns the number of prompt tokens served from the prefix cache, or
         None when not enough blocks are free/evictable (admission defers —
-        nothing is mutated)."""
-        assert slot not in self._slot_free_set and not self._slot_blocks[slot]
-        matched = self.match_prefix(prompt)
-        cached_len = len(matched) * self.block_size
-        span = self.plan_span(len(prompt), budget, chunk, cached_len)
-        n_total = -(-span // self.block_size)
-        need = n_total - len(matched)
-        matched_set = set(matched)
-        evictable = sum(1 for b in self._lru if b not in matched_set)
-        if need > len(self._free_set) + evictable:
-            return None
-        for b in matched:
-            if self._ref[b] == 0:
-                self._lru.pop(b)
-            self._ref[b] += 1
-        blocks = matched + [self._alloc_block() for _ in range(need)]
-        self._slot_blocks[slot] = blocks
-        self.tables[slot, :] = self.parking_block
-        self.tables[slot, :n_total] = blocks
-        return cached_len
+        nothing is mutated). Runs in a ``serve.kv_reserve`` span."""
+        with self.tracer.span("serve.kv_reserve"):
+            assert slot not in self._slot_free_set and not self._slot_blocks[slot]
+            matched = self.match_prefix(prompt)
+            cached_len = len(matched) * self.block_size
+            span = self.plan_span(len(prompt), budget, chunk, cached_len)
+            n_total = -(-span // self.block_size)
+            need = n_total - len(matched)
+            matched_set = set(matched)
+            evictable = sum(1 for b in self._lru if b not in matched_set)
+            if need > len(self._free_set) + evictable:
+                return None
+            for b in matched:
+                if self._ref[b] == 0:
+                    self._lru.pop(b)
+                self._ref[b] += 1
+            blocks = matched + [self._alloc_block() for _ in range(need)]
+            self._slot_blocks[slot] = blocks
+            self.tables[slot, :] = self.parking_block
+            self.tables[slot, :n_total] = blocks
+            return cached_len
 
     def register_prompt(self, slot: int, prompt: np.ndarray) -> int:
         """After the slot's prefill completed: publish its full prompt
         blocks into the prefix map so future requests can share them.
-        Returns how many new blocks were registered."""
+        Returns how many new blocks were registered. Runs in a
+        ``serve.kv_register`` span."""
         if not self.prefix_cache:
             return 0
         n = 0
-        for i, h in enumerate(hash_prompt_blocks(prompt, self.block_size)):
-            b = self._slot_blocks[slot][i]
-            if h in self._hash_to_block or b in self._block_hash:
-                continue  # already published (possibly by another slot)
-            self._hash_to_block[h] = b
-            self._block_hash[b] = h
-            n += 1
+        with self.tracer.span("serve.kv_register"):
+            for i, h in enumerate(hash_prompt_blocks(prompt, self.block_size)):
+                b = self._slot_blocks[slot][i]
+                if h in self._hash_to_block or b in self._block_hash:
+                    continue  # already published (possibly by another slot)
+                self._hash_to_block[h] = b
+                self._block_hash[b] = h
+                n += 1
         return n
 
     # -- copy-on-write ------------------------------------------------------

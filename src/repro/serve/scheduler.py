@@ -60,7 +60,6 @@ import numpy as np
 
 from repro.distributed.constraints import mesh_context
 from repro.models.base import ArchConfig, ModelAPI
-from repro.obs.profile import NULL_TIMER, StepTimer
 from repro.obs.trace import Tracer, get_tracer
 from repro.serve.compile_cache import BucketedPrefill, ChunkedPrefill
 from repro.serve.kv import KVSlotManager
@@ -118,7 +117,6 @@ class SlotScheduler:
         clock: Callable[[], float] = time.monotonic,
         tracer: Optional[Tracer] = None,
         registry=None,
-        profiler: Optional[StepTimer] = None,
         mesh=None,
         rules=None,
         spec_draft=None,
@@ -143,11 +141,10 @@ class SlotScheduler:
         self.max_len = max_len
         self.clock = clock
         # observability: explicit tracer wins, else the process-global hook
-        # (NULL_TRACER unless launch --trace-out installed one); registry and
-        # profiler stay None/NULL when the caller didn't opt in
+        # (NULL_TRACER unless launch --trace-out installed one); the registry
+        # stays None when the caller didn't opt in
         self.tracer = tracer if tracer is not None else get_tracer()
         self.registry = registry
-        self.profiler = profiler if profiler is not None else NULL_TIMER
         self._tick_compiled = False  # first _run_tick compiles the step
         self.mesh = mesh
         if mesh is not None:
@@ -235,13 +232,15 @@ class SlotScheduler:
         return int(bpt * sum(int(self._pos[i]) + 1 for i in active))
 
     def _run_tick(self) -> np.ndarray:
-        with self._mesh_ctx():
-            nxt, self.kv.cache = self._tick_fn(
-                self.params, self.kv.cache, jnp.asarray(self._tok), jnp.asarray(self._pos)
-            )
-        # repro: noqa-RPA001 -- the tick barrier: emitted tokens must reach
-        # the host to route into per-request queues / detect EOS
-        return np.asarray(nxt)
+        tracer = self.tracer
+        with tracer.span("serve.upload"):
+            tok, pos = jnp.asarray(self._tok), jnp.asarray(self._pos)
+        with self._mesh_ctx(), tracer.span("serve.dispatch"):
+            nxt, self.kv.cache = self._tick_fn(self.params, self.kv.cache, tok, pos)
+        with tracer.span("serve.token_pull"):
+            # repro: noqa-RPA001 -- the tick barrier: emitted tokens must reach
+            # the host to route into per-request queues / detect EOS
+            return np.asarray(nxt)
 
     def _build_tick(self):
         decode = self.api.decode_step
@@ -360,61 +359,55 @@ class SlotScheduler:
         the target's correction. Per row the advance ``e`` is 1..spec_k
         tokens; ``_pos += e`` IS the rollback — stale cache writes past the
         new position are causally masked and overwritten next round."""
-        prof = self.profiler
-        prof.tick()
-        with prof.phase("admit"):
-            self._admit()
+        self._admit()
         active = [i for i, s in enumerate(self._slots) if s is not None]
         if not active:
             return False
-        with prof.phase("decode"):
-            if not self._tick_compiled and self.tracer.enabled:
-                with self.tracer.span("compile", "scheduler", kind="spec_round",
-                                      n_slots=self.n_slots, spec_k=self.spec_k):
-                    window, nxt = self._run_spec_tick()
-            else:
+        if not self._tick_compiled and self.tracer.enabled:
+            with self.tracer.span("compile", "scheduler", kind="spec_round",
+                                  n_slots=self.n_slots, spec_k=self.spec_k):
                 window, nxt = self._run_spec_tick()
-            prof.sync(nxt)
-            self._tick_compiled = True
-        with prof.phase("host"):
-            self.metrics.record_step(
-                len(active), kv_bytes_read=self._decode_kv_bytes(active))
-            c = self.spec_k
-            drafted = accepted = 0
-            for i in active:
-                st = self._slots[i]
-                w, g = window[i], nxt[i]
-                # accept rule: longest prefix of draft tokens matching the
-                # target's greedy choices (w[j] drafted token j, g[j-1] the
-                # target's token after window slot j-1), then ALWAYS take
-                # the target's next token g[a-1] — exactness for free
-                a = 1
-                while a < c and w[a] == g[a - 1]:
-                    a += 1
-                e = 0
-                done = False
-                for j in range(a):
-                    e += 1
-                    done = self._emit(st, int(g[j]))
-                    if done:
-                        break  # budget/EOS truncation: e <= a tokens used
-                self._tok[i] = g[e - 1]
-                self._pos[i] += e
-                drafted += c - 1
-                accepted += e - 1
+        else:
+            window, nxt = self._run_spec_tick()
+        self._tick_compiled = True
+        self.metrics.record_step(
+            len(active), kv_bytes_read=self._decode_kv_bytes(active))
+        c = self.spec_k
+        drafted = accepted = 0
+        for i in active:
+            st = self._slots[i]
+            w, g = window[i], nxt[i]
+            # accept rule: longest prefix of draft tokens matching the
+            # target's greedy choices (w[j] drafted token j, g[j-1] the
+            # target's token after window slot j-1), then ALWAYS take
+            # the target's next token g[a-1] — exactness for free
+            a = 1
+            while a < c and w[a] == g[a - 1]:
+                a += 1
+            e = 0
+            done = False
+            for j in range(a):
+                e += 1
+                done = self._emit(st, int(g[j]))
                 if done:
-                    self._finish(st.req, st, i)
-                    self._slots[i] = None
-                    self._release_slot(i)
-                    self._tok[i] = 0
-                    self._pos[i] = 0
-            self.metrics.record_spec_round(len(active), drafted, accepted)
-            if self.tracer.enabled:
-                # per-round event carrying the same counts the metrics
-                # accumulate — trace_report-style reconciliation sums these
-                self.tracer.event("spec_round", track="scheduler",
-                                  rows=len(active), drafted=drafted,
-                                  accepted=accepted)
+                    break  # budget/EOS truncation: e <= a tokens used
+            self._tok[i] = g[e - 1]
+            self._pos[i] += e
+            drafted += c - 1
+            accepted += e - 1
+            if done:
+                self._finish(st.req, st, i)
+                self._slots[i] = None
+                self._release_slot(i)
+                self._tok[i] = 0
+                self._pos[i] = 0
+        self.metrics.record_spec_round(len(active), drafted, accepted)
+        if self.tracer.enabled:
+            # per-round event carrying the same counts the metrics
+            # accumulate — trace_report-style reconciliation sums these
+            self.tracer.event("spec_round", track="scheduler",
+                              rows=len(active), drafted=drafted,
+                              accepted=accepted)
         return True
 
     # -- queue --------------------------------------------------------------
@@ -504,8 +497,9 @@ class SlotScheduler:
         rm.t_admit = self.clock()
         logits, pcache = self.prefill(self.params, req.prompt)
         self.metrics.prefills += 1
-        # repro: noqa-RPA001 -- admission emits the prefill token to the host
-        t0 = int(np.argmax(np.asarray(logits)[0, -1]))
+        with self.tracer.span("serve.first_token", rid=req.rid):
+            # repro: noqa-RPA001 -- admission emits the prefill token to the host
+            t0 = int(np.argmax(np.asarray(logits)[0, -1]))
         plen = rm.prompt_len
         # decode writes go to plen .. plen+n-2; keep them inside the cache
         budget = min(req.max_new_tokens, self.max_len - plen + 1)
@@ -539,50 +533,49 @@ class SlotScheduler:
         """FIFO admission: the queue head either admits or (paged) defers —
         a deferral blocks everything behind it, which is what makes block
         backpressure deadlock-free (completions always free blocks)."""
-        while self.queue and self._slots_available:
-            if not self._admit_one(self.queue[0]):
-                break
-            self.queue.pop(0)
+        with self.tracer.span("serve.admit"):
+            while self.queue and self._slots_available:
+                if not self._admit_one(self.queue[0]):
+                    break
+                self.queue.pop(0)
 
     def tick(self) -> bool:
         """Admit waiting requests, then run one decode step over the slot
-        batch. Returns False when there was nothing to do. The optional
-        StepTimer samples every Nth tick, splitting wall time into admit
-        (queue + prefill) / decode (device step, synced in-phase) / host
-        (emit + EOS bookkeeping) phases."""
+        batch. Returns False when there was nothing to do. Its host work
+        runs in ``serve.*`` spans (obs/trace.py): ``serve.tick`` around it
+        all, ``serve.admit``, the step's ``serve.upload`` /
+        ``serve.dispatch`` / ``serve.token_pull``, and ``serve.emit``."""
         if self._spec_api is not None:
             return self._spec_tick()
-        prof = self.profiler
-        prof.tick()
-        with prof.phase("admit"):
+        tracer = self.tracer
+        with tracer.span("serve.tick"):
             self._admit()
-        active = [i for i, s in enumerate(self._slots) if s is not None]
-        if not active:
-            return False
-        with prof.phase("decode"):
-            if not self._tick_compiled and self.tracer.enabled:
-                with self.tracer.span("compile", "scheduler", kind="decode_tick",
-                                      n_slots=self.n_slots):
-                    nxt = prof.sync(self._run_tick())
+            active = [i for i, s in enumerate(self._slots) if s is not None]
+            if not active:
+                return False
+            if not self._tick_compiled and tracer.enabled:
+                with tracer.span("compile", "scheduler", kind="decode_tick",
+                                 n_slots=self.n_slots):
+                    nxt = self._run_tick()
             else:
-                nxt = prof.sync(self._run_tick())
+                nxt = self._run_tick()
             self._tick_compiled = True
-        with prof.phase("host"):
-            self.metrics.record_step(
-                len(active), kv_bytes_read=self._decode_kv_bytes(active))
-            for i in active:
-                st = self._slots[i]
-                self._tok[i] = nxt[i]
-                self._pos[i] += 1
-                if self._emit(st, int(nxt[i])):
-                    self._finish(st.req, st, i)
-                    self._slots[i] = None
-                    self._release_slot(i)
-                    # park the freed row at a safe in-bounds position; its
-                    # junk writes are overwritten by the next admission's
-                    # prefill (or land in the paged pool's parking block)
-                    self._tok[i] = 0
-                    self._pos[i] = 0
+            with tracer.span("serve.emit", rows=len(active)):
+                self.metrics.record_step(
+                    len(active), kv_bytes_read=self._decode_kv_bytes(active))
+                for i in active:
+                    st = self._slots[i]
+                    self._tok[i] = nxt[i]
+                    self._pos[i] += 1
+                    if self._emit(st, int(nxt[i])):
+                        self._finish(st.req, st, i)
+                        self._slots[i] = None
+                        self._release_slot(i)
+                        # park the freed row at a safe in-bounds position; its
+                        # junk writes are overwritten by the next admission's
+                        # prefill (or land in the paged pool's parking block)
+                        self._tok[i] = 0
+                        self._pos[i] = 0
         return True
 
     def run(self) -> List[Request]:
@@ -698,13 +691,15 @@ class PagedSlotScheduler(SlotScheduler):
         return int(per_row * len(active))
 
     def _run_tick(self) -> np.ndarray:
-        with self._mesh_ctx():
-            nxt, self.kv.cache = self._tick_fn(
-                self.params, self.kv.cache, jnp.asarray(self._tok),
-                jnp.asarray(self._pos), jnp.asarray(self.kv.tables),
-            )
-        # repro: noqa-RPA001 -- tick barrier (see SlotScheduler._run_tick)
-        return np.asarray(nxt)
+        tracer = self.tracer
+        with tracer.span("serve.upload"):
+            tok, pos = jnp.asarray(self._tok), jnp.asarray(self._pos)
+            tables = jnp.asarray(self.kv.tables)
+        with self._mesh_ctx(), tracer.span("serve.dispatch"):
+            nxt, self.kv.cache = self._tick_fn(self.params, self.kv.cache, tok, pos, tables)
+        with tracer.span("serve.token_pull"):
+            # repro: noqa-RPA001 -- tick barrier (see SlotScheduler._run_tick)
+            return np.asarray(nxt)
 
     def _build_tick(self):
         decode = self.api.decode_paged
@@ -749,7 +744,7 @@ class PagedSlotScheduler(SlotScheduler):
                               cached_tokens=cached)
         logits, self.kv.cache, n_chunks = self.prefill(
             self.params, self.kv.cache, self.kv.tables[slot], req.prompt, cached,
-            trace_track=f"slot{slot}", rid=req.rid,
+            rid=req.rid,
         )
         self.metrics.prefills += 1
         self.metrics.prefill_chunks += n_chunks
@@ -761,8 +756,9 @@ class PagedSlotScheduler(SlotScheduler):
         # publish this prompt's full blocks before any chance of freeing, so
         # even an instant-EOS request seeds the prefix cache
         self.kv.register_prompt(slot, req.prompt)
-        # repro: noqa-RPA001 -- admission emits the prefill token to the host
-        t0 = int(np.argmax(np.asarray(logits)[0, -1]))
+        with self.tracer.span("serve.first_token", rid=req.rid):
+            # repro: noqa-RPA001 -- admission emits the prefill token to the host
+            t0 = int(np.argmax(np.asarray(logits)[0, -1]))
         st = _SlotState(req=req, remaining=budget, emitted=[])
         done = self._emit(st, t0)
         self._trace_admission(req, slot, cached_tokens=cached, n_chunks=n_chunks)

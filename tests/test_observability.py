@@ -1,15 +1,17 @@
 """Observability layer tests (repro.obs + serve wiring): fake-clock span
 ordering, ring-buffer eviction, NullTracer no-ops, Perfetto export schema,
-Prometheus exposition format, step-timer sampling/accounting, the
-registry<->RunMetrics feed, and exact trace<->metrics reconciliation on a
-real scheduler run (the invariant benchmarks/trace_report.py --validate
-gates in CI)."""
+Prometheus exposition format, the serve loop's spans in a jax.profiler
+trace and in the Tracer, span self times, the registry<->RunMetrics feed,
+and exact trace<->metrics reconciliation on a real scheduler run (the
+invariant benchmarks/trace_report.py --validate gates in CI)."""
+import glob
 import json
 
+import jax
 import numpy as np
 import pytest
 
-from repro.obs.profile import NULL_TIMER, NullStepTimer, StepTimer, profile_trace
+from repro.obs.profile import profile_trace
 from repro.obs.registry import (
     Counter,
     Gauge,
@@ -22,6 +24,7 @@ from repro.obs.trace import (
     Tracer,
     get_tracer,
     records_to_perfetto,
+    self_times,
     set_tracer,
 )
 from repro.serve.metrics import RequestMetrics, RunMetrics
@@ -79,8 +82,8 @@ def test_null_tracer_noop():
     assert NULL_TRACER.enabled is False
     assert NULL_TRACER.event("x", rid=1) is None
     assert NULL_TRACER.add_span("x", "t", 0.0, 1.0) is None
-    # the disabled span context is one shared object — no per-call allocation
-    assert NULL_TRACER.span("a") is NULL_TRACER.span("b")
+    # the disabled span is the bare profiler annotation: it records nothing
+    assert isinstance(NULL_TRACER.span("a", rid=1), jax.profiler.TraceAnnotation)
     with NULL_TRACER.span("x"):
         pass
     assert len(NULL_TRACER) == 0 and NULL_TRACER.records == []
@@ -250,52 +253,51 @@ def test_registry_snapshot_json_roundtrip(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# StepTimer
+# profiler annotations and span self times
 # ---------------------------------------------------------------------------
 
 
-def test_step_timer_sampling_and_accounting():
-    clk = FakeClock(step=1.0)
-    st = StepTimer(sample_every=2, clock=clk)
-    for _ in range(4):  # ticks 0..3: ticks 0 and 2 sample
-        st.tick()
-        with st.phase("admit"):
-            pass
-        with st.phase("decode"):
-            pass
-    s = st.summary()
-    assert s["ticks"] == 4 and s["sampled_ticks"] == 2
-    assert s["sample_every"] == 2
-    # each sampled phase costs exactly one clock step (enter->exit)
-    assert s["phases"]["admit"]["calls"] == 2
-    assert s["phases"]["admit"]["total_s"] == pytest.approx(2.0)
-    assert s["phases"]["decode"]["mean_s"] == pytest.approx(1.0)
-    assert sum(p["fraction"] for p in s["phases"].values()) == pytest.approx(1.0)
-    # unsampled ticks hand out the shared null context: no clock reads
-    st.sampling = False
-    assert st.phase("admit") is st.phase("decode")
-    with pytest.raises(ValueError):
-        StepTimer(sample_every=0)
+def _host_events(log_dir):
+    """(name, start_ns, end_ns) of every event on the /host:CPU plane of the
+    one xplane under ``log_dir``."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for pl in ProfileData.from_file(path).planes if pl.name == "/host:CPU"
+            for ln in pl.lines for e in ln.events]
 
 
-def test_step_timer_streams_spans_to_tracer():
+@pytest.mark.parametrize("tracer", [NULL_TRACER, "recording"])
+def test_spans_land_in_the_profiler_trace(tmp_path, tracer):
+    tr = Tracer(clock=FakeClock()) if tracer == "recording" else tracer
+    with profile_trace(str(tmp_path)):
+        with tr.span("serve.tick"):
+            with tr.span("serve.upload", rows=2):
+                pass
+    ev = {n: (s, e) for n, s, e in _host_events(tmp_path)}
+    assert ev["serve.tick"][0] <= ev["serve.upload"][0] <= ev["serve.upload"][1] \
+        <= ev["serve.tick"][1]
+    # profile_trace turns the Python tracer off: no per-call events
+    assert not [n for n in ev if n.startswith("$")]
+    if tr.enabled:
+        assert [(r.name, r.track, r.ts, r.dur, r.args) for r in tr.records] == [
+            ("serve.upload", "scheduler", 101.0, 1.0, {"rows": 2}),
+            ("serve.tick", "scheduler", 100.0, 3.0, {})]
+
+
+def test_self_times_subtract_child_spans():
     tr = Tracer(clock=FakeClock())
-    st = StepTimer(sample_every=1, tracer=tr)
-    assert st.clock is tr.clock  # shared timeline with the scheduler spans
-    st.tick()
-    with st.phase("decode"):
-        pass
-    (rec,) = tr.records
-    assert rec.name == "decode" and rec.track == "profiler"
-    assert rec.args == {"tick": 0} and rec.dur == 1.0
-
-
-def test_null_step_timer():
-    assert NULL_TIMER.enabled is False
-    assert NULL_TIMER.tick() is False
-    assert NULL_TIMER.phase("a") is NULL_TIMER.phase("b")
-    assert NULL_TIMER.sync("x") == "x"
-    assert isinstance(NULL_TIMER, NullStepTimer)
+    tr.add_span("serve.tick", "scheduler", 0.0, 10.0)
+    tr.add_span("serve.admit", "scheduler", 1.0, 4.0)
+    tr.add_span("serve.kv_reserve", "scheduler", 1.5, 2.0)
+    tr.add_span("serve.dispatch", "scheduler", 5.0, 7.0)
+    tr.add_span("serve.tick", "scheduler", 10.0, 11.0)  # a sibling, not a child
+    tr.add_span("decode", "slot0", 0.0, 20.0)  # not a serve.* span
+    assert self_times(tr.records) == {"serve.tick": pytest.approx(6.0),
+                                      "serve.admit": pytest.approx(2.5),
+                                      "serve.kv_reserve": pytest.approx(0.5),
+                                      "serve.dispatch": pytest.approx(2.0)}
 
 
 def test_profile_trace_null_paths():
@@ -419,7 +421,7 @@ def test_trace_reconciles_with_metrics_exactly(lm, engine):
     reg = MetricsRegistry()
     eng = ServeEngine(api, params, cfg, engine=engine, n_slots=2, max_len=32,
                       kv_block_size=8, prefill_chunk=8, tracer=tracer,
-                      registry=reg, profile_sample=2)
+                      registry=reg)
     rng = np.random.RandomState(0)
     reqs = [Request(rid=i, prompt=rng.randint(0, cfg.vocab, 4 + i).astype(np.int32),
                     max_new_tokens=4) for i in range(4)]
@@ -444,15 +446,78 @@ def test_trace_reconciles_with_metrics_exactly(lm, engine):
     # registry saw every completion with the engine's labels
     assert reg.get("serve_requests_total").value(
         mode="dense", engine=engine, route=cfg.paged_attn_route) == 4
-    # profiler ticked and phases accounted
-    ps = eng.profiler.summary()
-    assert ps["sampled_ticks"] >= 1
-    assert set(ps["phases"]) == {"admit", "decode", "host"}
     # paged point events present
     names = {r.name for r in tracer.records if r.kind == "event"}
     assert "submit" in names
     if engine == "paged":
         assert "prefix_miss" in names or "prefix_hit" in names
+
+
+SERVE_SPANS = {"serve.tick", "serve.admit", "serve.first_token", "serve.upload",
+               "serve.dispatch", "serve.token_pull", "serve.emit"}
+PAGED_SPANS = {"serve.kv_reserve", "serve.prefill_chunk", "serve.kv_register"}
+
+
+def _serve_tiny(lm, engine, tracer=None):
+    from repro.serve.engine import Request, ServeEngine
+
+    cfg, api, params = lm
+    eng = ServeEngine(api, params, cfg, engine=engine, n_slots=2, max_len=32,
+                      kv_block_size=8, prefill_chunk=8, tracer=tracer)
+    rng = np.random.RandomState(2)
+    for i in range(3):
+        eng.submit(Request(rid=i, prompt=rng.randint(0, cfg.vocab, 9 + i).astype(np.int32),
+                           max_new_tokens=3))
+    assert len(eng.run()) == 3
+    return eng
+
+
+def _expected_spans(engine):
+    return SERVE_SPANS | (PAGED_SPANS if engine == "paged" else set())
+
+
+@pytest.mark.parametrize("engine", ["continuous", "paged"])
+def test_serve_spans_in_the_profiler_trace(lm, engine, tmp_path):
+    """With no Tracer recording, the serve loop's spans still reach a
+    jax.profiler trace's host plane, nested as the tick runs them."""
+    with profile_trace(str(tmp_path)):
+        eng = _serve_tiny(lm, engine)
+    serve = [(n, s, e) for n, s, e in _host_events(tmp_path) if n.startswith("serve.")]
+    assert {n for n, _, _ in serve} == _expected_spans(engine)
+    ticks = [(s, e) for n, s, e in serve if n == "serve.tick"]
+    for n, s, e in serve:
+        if n in ("serve.upload", "serve.dispatch", "serve.token_pull"):
+            assert any(ts <= s and e <= te for ts, te in ticks), n
+    pulls = sum(n == "serve.token_pull" for n, _, _ in serve)
+    assert pulls == eng.metrics.decode_steps > 0
+
+
+@pytest.mark.parametrize("engine", ["continuous", "paged"])
+def test_tracer_records_serve_spans_on_the_scheduler_track(lm, engine):
+    tracer = Tracer(clock=FakeClock())
+    eng = _serve_tiny(lm, engine, tracer)
+    serve = [r for r in tracer.records if r.name.startswith("serve.")]
+    assert {r.name for r in serve} == _expected_spans(engine)
+    assert {r.track for r in serve} == {"scheduler"}
+    assert sum(r.name == "serve.token_pull" for r in serve) == eng.metrics.decode_steps
+    own = self_times(tracer.records)
+    assert set(own) == _expected_spans(engine) and min(own.values()) >= 0
+
+
+def test_launcher_prints_serve_span_self_times(tmp_path, capsys):
+    from repro.launch.serve import main
+
+    out = tmp_path / "trace.jsonl"
+    assert main(["--arch", "smollm-360m", "--smoke", "--engine", "paged",
+                 "--requests", "3", "--new-tokens", "3", "--max-len", "32",
+                 "--kv-block-size", "8", "--prefill-chunk", "8",
+                 "--trace-out", str(out), "--profile-dir", str(tmp_path / "prof")]) == 0
+    assert "[serve] profile (serve.* span self times): serve." in capsys.readouterr().out
+    names = {json.loads(ln).get("name") for ln in out.read_text().splitlines()}
+    assert SERVE_SPANS | PAGED_SPANS <= names
+    host = {n for n, _, _ in _host_events(tmp_path / "prof")}
+    assert SERVE_SPANS | PAGED_SPANS <= host
+    assert not [n for n in host if n.startswith("$")]
 
 
 def test_trace_report_validate_on_real_run(lm, tmp_path):
@@ -498,18 +563,16 @@ def test_trace_report_validate_on_real_run(lm, tmp_path):
 
 
 def test_disabled_path_emits_nothing(lm):
-    """Default construction (no tracer/registry/profiler) keeps the global
-    NULL_TRACER silent and the scheduler's profiler the shared NULL_TIMER."""
+    """Default construction (no tracer/registry) keeps the global
+    NULL_TRACER silent."""
     from repro.serve.engine import Request, ServeEngine
 
     cfg, api, params = lm
     eng = ServeEngine(api, params, cfg, engine="continuous", n_slots=2,
                       max_len=32)
     assert eng.scheduler.tracer is NULL_TRACER
-    assert eng.scheduler.profiler is NULL_TIMER
     eng.submit(Request(rid=0, prompt=np.arange(1, 5, dtype=np.int32),
                        max_new_tokens=2))
     done = eng.run()
     assert len(done) == 1
     assert len(NULL_TRACER) == 0
-    assert NULL_TIMER.ticks == 0
