@@ -3,25 +3,32 @@ the traced window, in percent. Operations and bytes come from the live
 context of every call in the window (``bench/kernels/paged_attn.py``: the
 decode ticks' active rows and the prefill chunks' real tokens, one call per
 layer); the time is the summed device time of the kernel's ops in the
-trace. The program gives the Pallas kernel no name, so its ops are found as
-the ``tpu_custom_call`` ops that take one layer of the KV pool
-(``[<blocks>,<block>,<kv heads>,<head dim>]``): the only Pallas kernel on
-this path. The least time is the larger of operations over the chip's
-bf16 peak, its one published matmul peak (at "highest" the kernel's f32
-dots take several bf16 passes, so this bound is the lower one), and bytes
-over HBM bandwidth."""
+trace. The ops are found by the kernel's name: the ``tpu_custom_call`` ops
+whose HLO instruction is named ``paged_attention`` (the ``pallas_call``'s
+name), whatever layout the KV pool has. The least time is the larger of
+operations over the chip's bf16 peak, its one published matmul peak (at
+"highest" the kernel's f32 dots take several bf16 passes, so this bound is
+the lower one), and bytes over HBM bandwidth."""
 import sys
 
 from bench import model, peaks
 from bench.kernels import paged_attn
 
+KERNEL = "paged_attention"  # the pallas_call's name in kernels/paged_attn.py
+
+
+def is_kernel(op: str) -> bool:
+    """Whether a device op (its HLO text) is a call of the kernel: a
+    ``tpu_custom_call`` whose instruction is ``%paged_attention[.<n>]``."""
+    inst = op.partition(" = ")[0].lstrip("%")
+    return ('custom_call_target="tpu_custom_call"' in op
+            and (inst == KERNEL or inst.startswith(KERNEL + ".")))
+
 
 def read(run):
     cfg, w = run.cfg, run.rec.trace_window
     hq, hkv, d = cfg["num_attention_heads"], cfg["num_key_value_heads"], model.head_dim(cfg)
-    pool = f",{cfg['serve']['kv_block_size']},{hkv},{d}]"
-    secs, n = run.trace.kernel_s(lambda name: 'custom_call_target="tpu_custom_call"' in name
-                                 and pool in name)
+    secs, n = run.trace.kernel_s(is_kernel)
     if not n:
         return None
     layers = cfg["num_hidden_layers"]

@@ -131,9 +131,11 @@ def make_params(cfg: dict, api, seed: int):
     return jax.block_until_ready(jax.jit(build)(seed_key(seed)))
 
 
-def build_engine(cfg: dict, seed: int, *, overrides: dict | None = None):
+def build_engine(cfg: dict, seed: int, *, overrides: dict | None = None,
+                 mark=lambda phase: None):
     """The paged engine serving this configuration with weights from the
-    seed. ``overrides`` replace ArchConfig fields (the control's bfloat16)."""
+    seed. ``overrides`` replace ArchConfig fields (the control's bfloat16);
+    ``mark(phase)`` is called as the weights and then the engine are done."""
     from repro.models import build_model
     from repro.serve.engine import ServeEngine
 
@@ -142,8 +144,12 @@ def build_engine(cfg: dict, seed: int, *, overrides: dict | None = None):
     if overrides:
         arch = arch.replace(**overrides)
     api = build_model(arch, phase="serve")
-    return ServeEngine(api, make_params(cfg, api, seed), arch, engine=sv["engine"],
-                       batch_size=sv["n_slots"], n_slots=sv["n_slots"], max_len=sv["max_len"],
-                       quantized_kv=sv["quantized_kv"], kv_block_size=sv["kv_block_size"],
-                       kv_n_blocks=sv["kv_n_blocks"], prefix_cache=sv["prefix_cache"],
-                       prefill_chunk=sv["prefill_chunk"])
+    params = make_params(cfg, api, seed)
+    mark("weights")
+    eng = ServeEngine(api, params, arch, engine=sv["engine"],
+                      batch_size=sv["n_slots"], n_slots=sv["n_slots"], max_len=sv["max_len"],
+                      quantized_kv=sv["quantized_kv"], kv_block_size=sv["kv_block_size"],
+                      kv_n_blocks=sv["kv_n_blocks"], prefix_cache=sv["prefix_cache"],
+                      prefill_chunk=sv["prefill_chunk"])
+    mark("engine")
+    return eng

@@ -17,6 +17,10 @@ first ``TRACE_SECONDS`` of the window with the JAX profiler and reports the
 cell's per-layer metrics. After the window the engine is freed and the
 plain reference checks a sample of what was served (``bench/check.py``).
 
+``setup_s`` is the program's set-up: process start to traffic start, plus
+the seconds of every compile after traffic start. Stderr splits it into
+phases (``Phases``) and prints the traffic's warm-up or ramp apart.
+
 The last line of stdout is one JSON object; the numbers compared, each
 with its limit, are the last lines of stderr and the result's last key.
 Without a TPU, or with fewer chips than the cell asks for, it exits with
@@ -55,6 +59,27 @@ MATMUL_PRECISION = "highest"
 
 class NoAccelerator(RuntimeError):
     pass
+
+
+class Phases:
+    """Host seconds of each set-up phase; ``mark(name)`` ends one. In order:
+    ``imports`` (JAX among them), ``device`` (the TPU runtime's start),
+    ``weights``, ``engine``, ``warm_up`` and the harness's ``traffic_gen``,
+    which takes tens of milliseconds and so stays in ``setup_s``."""
+
+    def __init__(self, t0: float):
+        self.t, self.secs = t0, {}
+
+    def mark(self, name: str) -> None:
+        now = time.perf_counter()
+        self.secs[name] = now - self.t
+        self.t = now
+
+
+def setup_seconds(t_process: float, rec) -> float:
+    """The program's set-up: process start to traffic start, plus every
+    compile after traffic start (``rec`` is the window's ``RunRecord``)."""
+    return rec.t_traffic - t_process + rec.compile_s
 
 
 def log(*a):
@@ -105,27 +130,32 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, *,
     cfg = cfg or manifest.config_file(bench, wl["config"])
     traffic = traffic or manifest.traffic_file(wl["traffic"])
     limits = limits or manifest.cell_file(cell)["limits"]
+    phases = Phases(t0)
+    phases.mark("imports")
     dev = device_info(wl["chips"], require_tpu)
+    phases.mark("device")
     with jax.default_matmul_precision(MATMUL_PRECISION):
         return _serve_and_check(cell, seed, seconds, trace, bench, cfg, traffic, limits, dev,
-                                overrides, t0, engine_hook, verify)
+                                overrides, t0, phases, engine_hook, verify)
 
 
 def _serve_and_check(cell, seed, seconds, trace, bench, cfg, traffic, limits, dev, overrides,
-                     t0, engine_hook, verify):
+                     t0, phases, engine_hook, verify):
     import jax
 
     from bench import check, model, stats, traffic_gen
     from bench.serve_window import Driver
 
-    eng = model.build_engine(cfg, seed, overrides=overrides)
+    eng = model.build_engine(cfg, seed, overrides=overrides, mark=phases.mark)
     if engine_hook is not None:
         engine_hook(eng)
     drv = Driver(eng)
     sv = cfg["serve"]
     drv.warm_up(prompt_len=sv["prefill_chunk"] + 1, new_tokens=2, vocab=cfg["vocab_size"])
+    phases.mark("warm_up")
     items = traffic_gen.generate(traffic, seed, cfg["vocab_size"],
                                  traffic_gen.requests_needed(traffic, seconds))
+    phases.mark("traffic_gen")
     trace_dir = os.path.join(WORK_DIR, f"trace-{cell}")
     if trace:
         shutil.rmtree(trace_dir, ignore_errors=True)
@@ -146,7 +176,7 @@ def _serve_and_check(cell, seed, seconds, trace, bench, cfg, traffic, limits, de
                      trace_s=min(seconds, TRACE_SECONDS))
     rec = drv.run(items, seconds, traffic["window"],
                   min_check_tokens=cfg["check"]["min_served_tokens"], **hooks)
-    setup_s = rec.window.open - t0
+    setup_s = setup_seconds(t0, rec)
     device = jax.devices()[0]
     dev["memory_peak_bytes"] = int((device.memory_stats() or {}).get("peak_bytes_in_use", 0))
     w = rec.window
@@ -165,10 +195,14 @@ def _serve_and_check(cell, seed, seconds, trace, bench, cfg, traffic, limits, de
         f"not admitted at the close, "
         f"{rec.compiles_in_window} compiles in the window, generator late p95 "
         f"{rec.late_submit_p95_s * 1e3:.3f} ms, {len(rec.outputs)} requests finished")
+    log("set-up phases (s): " + ", ".join(f"{k} {v}" for k, v in phases.secs.items())
+        + f", to traffic start {rec.t_traffic - t0}, compiles after traffic start "
+        f"{rec.compiles_in_traffic} ({rec.compile_s} s); setup_s {setup_s}")
     if drv.saturated_at is not None:
-        log(f"ramp: saturated {rec.ramp_s:.3f} s into the traffic, with "
-            f"{drv.saturated_at[0]}/{sv['n_slots']} slots busy and {drv.saturated_at[1]}/"
-            f"{drv.sched.kv.n_blocks} KV blocks in use")
+        log(f"ramp_s: {rec.ramp_s} (saturated, with {drv.saturated_at[0]}/{sv['n_slots']} "
+            f"slots busy and {drv.saturated_at[1]}/{drv.sched.kv.n_blocks} KV blocks in use)")
+    else:
+        log(f"ramp_s: {rec.ramp_s} (warm-up of {traffic['window']['warm_s']} s)")
     log(f"program counters over the window: {json.dumps(rec.counters)}")
     prompts = {it.rid: it.prompt for it in items}
     asked = {it.rid: it.max_new_tokens for it in items}

@@ -16,6 +16,12 @@ left requests waiting because every slot or the pool's blocks were taken.
 It closes ``seconds`` later. With ``drain: first_tokens`` the engine keeps
 serving (arrivals included) until every request due in the window has its
 first token, up to ``drain_limit_s``.
+
+Set-up ends when the traffic starts (``RunRecord.t_traffic``), so the
+traffic's warm-up or ramp (``ramp_s``) is not set-up. Every backend compile
+from then to the end of serving is counted with its seconds
+(``compile_s``), which the harness adds to set-up: program work that moves
+out of set-up into the first seconds of traffic still shows there.
 """
 from __future__ import annotations
 
@@ -55,7 +61,7 @@ class RunRecord:
     ticks: List[TickRecord]
     prefills: List[PrefillRecord]
     window: Window
-    t_traffic: float  # host time traffic started
+    t_traffic: float  # host time traffic started (set-up ends here)
     ramp_s: float  # traffic start -> window open
     drain_s: float  # window close -> serving stopped
     t_stop: float  # host time the drain ended (missing first tokens count to here)
@@ -64,6 +70,8 @@ class RunRecord:
     counters: Dict[str, float]  # the program's RunMetrics over the window
     outputs: Dict[int, np.ndarray]  # tokens of every finished request
     compiles_in_window: int
+    compiles_in_traffic: int  # compiles from traffic start to the end of serving
+    compile_s: float  # their summed seconds, which count as set-up
     late_submit_p95_s: float  # how late the generator submitted, p95
 
 
@@ -87,12 +95,16 @@ class _PrefillTap:
 
 
 class _CompileCounter:
+    """Counts the backend compiles of the process and sums their seconds."""
+
     def __init__(self):
         self.n = 0
+        self.secs = 0.0
 
     def __call__(self, event, secs, **_):
         if event == "/jax/core/compile/backend_compile_duration":
             self.n += 1
+            self.secs += secs
 
 
 class Driver:
@@ -159,6 +171,7 @@ class Driver:
         late: List[float] = []
         opens = window_cfg["opens"]
         t_start = clock()
+        compiles_start, compile_s_start = self._compiles.n, self._compiles.secs
         t_open = t_start + float(window_cfg.get("warm_s", 0)) if opens == "after_warm" else None
         if opens not in ("after_warm", "saturated"):
             raise ValueError(f"unknown window opening {opens!r}")
@@ -251,4 +264,6 @@ class Driver:
             check_wait_s=t_stop - (t_drained or t_stop),
             trace_window=Window(t_trace0, t_trace_end) if t_trace_end else None,
             counters=counters, outputs=outputs, compiles_in_window=compiles_in_window,
+            compiles_in_traffic=self._compiles.n - compiles_start,
+            compile_s=self._compiles.secs - compile_s_start,
             late_submit_p95_s=late[min(len(late) - 1, int(0.95 * len(late)))] if late else 0.0)
