@@ -22,6 +22,7 @@ PRs have a perf trajectory to regress against.
 """
 from __future__ import annotations
 
+import functools
 import json
 import os
 from typing import Dict, List
@@ -196,32 +197,32 @@ def main(quick: bool = True) -> List[str]:
         import numpy as _np
 
         from repro.kernels import ref as _ref
-        gather_jit = jax.jit(_ref.paged_attention_ref)
+        from repro.models.base import PagedKVLayout
         hqa, hkva, da = 4, 2, 16
+        gather_jit = jax.jit(functools.partial(_ref.paged_attention_ref, n_kv_heads=hkva))
         for ns, ml, bsz in ((2, 64, 8), (2, 128, 16), (4, 128, 16)):
             tt = ml // bsz
             npb = ns * tt + 1
+            lanes = PagedKVLayout(1, npb, bsz, hkva, da).row_lanes
             kq = jax.random.split(jax.random.PRNGKey(ns * ml), 3)
             qa = jax.random.normal(kq[0], (ns, 1, hqa, da))
             tbl = jnp.asarray(_np.arange(ns * tt, dtype=_np.int32).reshape(ns, tt))
             qp = jnp.full((ns, 1), 3 * ml // 4 - 1, jnp.int32)
             for quant in (False, True):
                 if quant:
-                    ka = jax.random.randint(kq[1], (npb, bsz, hkva, da),
-                                            -127, 128, jnp.int8)
-                    va = jax.random.randint(kq[2], (npb, bsz, hkva, da),
-                                            -127, 128, jnp.int8)
-                    sc = jnp.full((npb, bsz, hkva, 1), 0.01, jnp.float32)
+                    ka = jax.random.randint(kq[1], (npb, bsz, lanes), -127, 128, jnp.int8)
+                    va = jax.random.randint(kq[2], (npb, bsz, lanes), -127, 128, jnp.int8)
+                    sc = jnp.full((npb, bsz, hkva), 0.01, jnp.float32)
                     scales = dict(k_scale=sc, v_scale=sc)
                 else:
-                    ka = jax.random.normal(kq[1], (npb, bsz, hkva, da))
-                    va = jax.random.normal(kq[2], (npb, bsz, hkva, da))
+                    ka = jax.random.normal(kq[1], (npb, bsz, lanes))
+                    va = jax.random.normal(kq[2], (npb, bsz, lanes))
                     scales = {}
                 tag = f"s{ns}_L{ml}_b{bsz}" + ("_int8" if quant else "")
                 t_g = timed(lambda: gather_jit(qa, ka, va, tbl, qp, **scales),
                             iters=2, warmup=1)
                 t_f = timed(lambda: ops.paged_attention(qa, ka, va, tbl, qp,
-                                                        **scales),
+                                                        n_kv_heads=hkva, **scales),
                             iters=2, warmup=1)
                 _record(results, f"paged_gather_{tag}", t_g,
                         "1.00x baseline (XLA gather + full softmax)", rows)

@@ -135,6 +135,7 @@ def kernel_parity():
 
     from repro.core.backend import pack_signs
     from repro.kernels import ops, ref
+    from repro.models.base import PagedKVLayout
 
     rng = np.random.RandomState(SEED)
     f32 = np.float32
@@ -170,6 +171,7 @@ def kernel_parity():
     hq, hkv, d = 15, 5, 64
     t = MAX_LEN // BLOCK_SIZE
     n_phys = N_SLOTS * t + 1
+    lanes = PagedKVLayout(1, n_phys, BLOCK_SIZE, hkv, d).row_lanes
     for label, b, c in (("decode", N_SLOTS, 1), ("chunk", 1, CHUNK)):
         tables = np.stack([rng.permutation(n_phys)[:t] for _ in range(b)]).astype(np.int32)
         if c == 1:
@@ -179,22 +181,23 @@ def kernel_parity():
         q = rng.randn(b, c, hq, d).astype(f32)
         for quant in (False, True):
             if quant:
-                k = rng.randint(-127, 128, (n_phys, BLOCK_SIZE, hkv, d)).astype(np.int8)
-                v = rng.randint(-127, 128, (n_phys, BLOCK_SIZE, hkv, d)).astype(np.int8)
-                ks = rng.uniform(0.005, 0.02, (n_phys, BLOCK_SIZE, hkv, 1)).astype(f32)
-                vs = rng.uniform(0.005, 0.02, (n_phys, BLOCK_SIZE, hkv, 1)).astype(f32)
+                k = rng.randint(-127, 128, (n_phys, BLOCK_SIZE, lanes)).astype(np.int8)
+                v = rng.randint(-127, 128, (n_phys, BLOCK_SIZE, lanes)).astype(np.int8)
+                ks = rng.uniform(0.005, 0.02, (n_phys, BLOCK_SIZE, hkv)).astype(f32)
+                vs = rng.uniform(0.005, 0.02, (n_phys, BLOCK_SIZE, hkv)).astype(f32)
                 scales = (ks, vs)
             else:
-                k = rng.randn(n_phys, BLOCK_SIZE, hkv, d).astype(f32)
-                v = rng.randn(n_phys, BLOCK_SIZE, hkv, d).astype(f32)
+                k = rng.randn(n_phys, BLOCK_SIZE, lanes).astype(f32)
+                v = rng.randn(n_phys, BLOCK_SIZE, lanes).astype(f32)
                 scales = ()
             attn = lambda *a: ops.paged_attention(
-                *a[:5], k_scale=a[5] if quant else None,
+                *a[:5], n_kv_heads=hkv, k_scale=a[5] if quant else None,
                 v_scale=a[6] if quant else None)
             got = jax.jit(attn)(q, k, v, tables, q_pos, *scales)
             with jax.default_matmul_precision("highest"):
                 got_f32 = jax.jit(attn)(q, k, v, tables, q_pos, *scales)
-                want = jax.jit(ref.paged_attention_ref)(q, k, v, tables, q_pos, *scales)
+                want = jax.jit(lambda *a: ref.paged_attention_ref(*a, n_kv_heads=hkv))(
+                    q, k, v, tables, q_pos, *scales)
             name = f"paged_attn {label} B{b} C{c} {'int8' if quant else 'fp'} KV"
             for prec, out, tol in (("default", got, ATTN_ATOL),
                                    ("highest", got_f32, ATTN_ATOL_F32)):

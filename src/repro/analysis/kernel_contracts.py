@@ -44,6 +44,8 @@ from repro.configs import ARCH_NAMES, get_config
 from repro.hwsim.resource import KERNEL_VMEM_BUDGET
 from repro.kernels import autotune, ops
 from repro.kernels.bnn_matmul import PACKED_WINDOW
+from repro.kernels.paged_attn import legal_block_h
+from repro.models.base import PagedKVLayout
 
 from .report import Finding, Report
 
@@ -154,13 +156,14 @@ def matmul_vmem_bytes(route: str, bl: Dict[str, int]) -> int:
 
 
 def paged_attn_vmem_bytes(c: int, bs: int, bh: int, g: int, d: int,
-                          *, quantized: bool = False) -> int:
+                          *, quantized: bool = False, hkv: Optional[int] = None) -> int:
     """One grid step of the fused paged-attention kernel: q/out windows
-    (1, bh, g*C, D), k/v pool windows (1, bs, bh, D), per-block scales when
-    quantized, and the online-softmax scratch (m, l, acc)."""
+    (1, bh, g*C, D), k/v pool windows (1, bs, bh*D), per-block scales of
+    every one of the ``hkv`` heads (default ``bh``) when quantized, and the
+    online-softmax scratch (m, l, acc)."""
     kv_w = 1 if quantized else 4
     q_out = 2 * c * bh * g * d * 4
-    kv = 2 * bs * bh * d * kv_w + (2 * bs * bh * 4 if quantized else 0)
+    kv = 2 * bs * bh * d * kv_w + (2 * bs * (hkv or bh) * 4 if quantized else 0)
     scratch = c * bh * g * (2 + d) * 4  # m, l, acc
     return q_out + kv + scratch
 
@@ -258,11 +261,12 @@ def check_paged_attn_contract(n_slots: int, max_len: int, block_size: int,
                                    overrides=blocks or None)
     bh = bl["block_h"]
     findings: List[Finding] = []
-    if bh < 1 or hkv % bh:
+    if bh < 1 or hkv % bh or bh != legal_block_h(hkv, d, bh):
         findings.append(_f(
             "KCV002", where,
-            f"block_h={bh} does not divide kv_heads={hkv}",
-            "get_paged_blocks clamps to a divisor; explicit overrides must too",
+            f"block_h={bh} does not divide kv_heads={hkv} into pool slabs of "
+            "a multiple of 128 lanes or the whole row",
+            "get_paged_blocks clamps with legal_block_h; explicit overrides must too",
             block_h=bh, kv_heads=hkv))
     if max_len % block_size:
         findings.append(_f(
@@ -279,7 +283,7 @@ def check_paged_attn_contract(n_slots: int, max_len: int, block_size: int,
     g = hq // max(hkv, 1) if hkv and hq % hkv == 0 else 1
     t = max(max_len // max(block_size, 1), 1)
     grid = (n_slots, max(hkv // max(bh, 1), 1), t)
-    vmem = paged_attn_vmem_bytes(c, block_size, bh, g, d, quantized=quantized)
+    vmem = paged_attn_vmem_bytes(c, block_size, bh, g, d, quantized=quantized, hkv=hkv)
     if vmem > vmem_budget:
         findings.append(_f(
             "KCV004", where,
@@ -334,11 +338,12 @@ def abstract_eval_route(route: str, m: int, k: int, n: int,
             hkv, hq, d = cfg.n_kv_heads, cfg.n_heads, cfg.hd
             if hq % hkv:
                 return None, ()  # GQA contract violation reported by KCV002
+            lanes = PagedKVLayout(1, m * t + 1, bs, hkv, d).row_lanes
             out = jax.eval_shape(
-                fn,
+                functools.partial(fn, n_kv_heads=hkv),
                 _sds((m, 1, hq, d), _F32),
-                _sds((m * t + 1, bs, hkv, d), _F32),
-                _sds((m * t + 1, bs, hkv, d), _F32),
+                _sds((m * t + 1, bs, lanes), _F32),
+                _sds((m * t + 1, bs, lanes), _F32),
                 _sds((m, t), jnp.int32),
                 _sds((m, 1), jnp.int32),
             )

@@ -40,6 +40,7 @@ __all__ = [
     "api_param_shardings",
     "replicated_sharding",
     "kv_cache_shardings",
+    "paged_kv_shardings",
 ]
 
 # Default tensor-parallel rule table. Entries may map one logical axis to a
@@ -181,6 +182,30 @@ def kv_cache_shardings(mesh: Mesh, cache, rules: ShardingRules = ShardingRules()
         return named_sharding(mesh, KV_CACHE_LOGICAL_AXES, rules, tuple(leaf.shape))
 
     return jax.tree_util.tree_map(one, cache)
+
+
+def paged_kv_shardings(mesh: Mesh, pool, layout, rules: ShardingRules = ShardingRules()):
+    """NamedShardings for the paged KV block pool (the ``models/base.py``
+    ``PagedKVLayout`` contract ``layout``: leaves ``(layers, blocks,
+    block_size, row_lanes)``, int8 scales ``(..., kv_heads)``).
+
+    The lane dim maps to ``kv_heads``. It splits only when the heads divide
+    the mapped mesh axes and the rows are not padded: a contiguous split of
+    the lanes is then a split by head group, and each device owns whole
+    heads for every block and offset. Otherwise the pool replicates, since
+    a split of the lanes would cut heads apart.
+    """
+    from repro.models.base import PAGED_KV_LOGICAL_AXES
+
+    axes = _filter_axes(mesh, rules.lookup("kv_heads"))
+    by_head = (layout.n_kv_heads % mesh_axis_size(mesh, axes) == 0
+               and layout.row_lanes == layout.kv_width)
+    logical = PAGED_KV_LOGICAL_AXES if by_head else None
+
+    def one(leaf):
+        return named_sharding(mesh, logical, rules, tuple(leaf.shape))
+
+    return jax.tree_util.tree_map(one, pool)
 
 
 def batch_sharding(mesh: Mesh, ndim: int = 2, batch_dim: int = 0,

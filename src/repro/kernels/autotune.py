@@ -36,6 +36,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 
 from .beats import LANE, beat_window
+from .paged_attn import legal_block_h, pool_row_lanes
 
 __all__ = [
     "DEFAULT_BLOCKS",
@@ -455,7 +456,9 @@ def _trace_search(shape_key: str, winner: Dict[str, int], best_t: float,
 # Not an (M, K, N) contraction — the problem is keyed on the serving shape
 # ``(n_slots, max_len, block_size, hd)`` (+ kv_heads, which the only tunable
 # must divide) and the block table has one knob: ``block_h``, the kv heads
-# folded into one grid step. Each step's VMEM working set is
+# folded into one grid step. The pool is lane-dense, so ``block_h * hd``
+# must be a multiple of 128 lanes or the whole row (``legal_block_h``).
+# Each step's VMEM working set is
 # ``2 * block_size * block_h * hd`` pool elements (k + v) plus the per-step
 # q/accumulator tiles, so block_h trades grid-step count against VMEM
 # pressure exactly like block_k_sub does for the matmul beats. The same
@@ -474,22 +477,18 @@ def paged_attn_cache_key(n_slots: int, max_len: int, block_size: int,
 
 def heuristic_paged_blocks(n_slots: int, max_len: int, block_size: int,
                            hd: int, kv_heads: int) -> Dict[str, int]:
-    """Largest divisor of kv_heads whose (k + v) step tile stays inside the
-    sub-tile budget. Serving shapes are small enough that this is usually
-    ``kv_heads`` itself (one grid step per (row, block))."""
+    """Largest legal ``block_h`` (``legal_block_h``) whose (k + v) step tile
+    stays inside the sub-tile budget. Serving shapes are small enough that
+    this is usually ``kv_heads`` itself (one grid step per (row, block))."""
     bh = max(kv_heads, 1)
     while bh > 1 and 2 * block_size * bh * hd > SUBTILE_BUDGET:
         bh -= 1
-    while kv_heads % bh:
-        bh -= 1
-    return {"block_h": bh}
+    return {"block_h": legal_block_h(kv_heads, hd, bh)}
 
 
-def _clamp_paged(kv_heads: int, bl: Dict[str, int]) -> Dict[str, int]:
-    bh = max(1, min(int(bl.get("block_h", kv_heads)), max(kv_heads, 1)))
-    while kv_heads % bh:
-        bh -= 1
-    return {"block_h": bh}
+def _clamp_paged(kv_heads: int, hd: int, bl: Dict[str, int]) -> Dict[str, int]:
+    return {"block_h": legal_block_h(max(kv_heads, 1), hd,
+                                     int(bl.get("block_h", kv_heads)))}
 
 
 def get_paged_blocks(
@@ -503,7 +502,7 @@ def get_paged_blocks(
 ) -> Dict[str, int]:
     """Resolve ``{"block_h"}`` for one paged-attention call site. Same
     priority order as ``get_blocks``: explicit overrides > measured cache >
-    heuristic, clamped to a divisor of kv_heads."""
+    heuristic, clamped by ``legal_block_h``."""
     bl = heuristic_paged_blocks(n_slots, max_len, block_size, hd, kv_heads)
     if use_cache:
         hit = _load_cache().get(
@@ -516,7 +515,7 @@ def get_paged_blocks(
         if unknown:
             raise TypeError(f"unknown paged_attn override(s): {sorted(unknown)}")
         bl.update(ov)
-    return _clamp_paged(kv_heads, bl)
+    return _clamp_paged(kv_heads, hd, bl)
 
 
 def measured_paged_blocks(
@@ -547,8 +546,9 @@ def measured_paged_blocks(
     n_phys = n_slots * t + 1
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (n_slots, 1, hq, hd), jnp.float32)
-    k = jax.random.normal(ks[1], (n_phys, block_size, kv_heads, hd), jnp.float32)
-    v = jax.random.normal(ks[2], (n_phys, block_size, kv_heads, hd), jnp.float32)
+    lanes = pool_row_lanes(kv_heads, hd)
+    k = jax.random.normal(ks[1], (n_phys, block_size, lanes), jnp.float32)
+    v = jax.random.normal(ks[2], (n_phys, block_size, lanes), jnp.float32)
     import numpy as np
 
     tables = jnp.asarray(
@@ -556,12 +556,13 @@ def measured_paged_blocks(
     q_pos = jnp.full((n_slots, 1), max(3 * max_len // 4 - 1, 0), jnp.int32)
 
     if candidates is None:
-        candidates = [bh for bh in range(1, kv_heads + 1) if kv_heads % bh == 0]
+        candidates = sorted({legal_block_h(kv_heads, hd, bh)
+                             for bh in range(1, kv_heads + 1)})
     best, best_t = None, float("inf")
     timings = []
     for bh in candidates:
-        cl = _clamp_paged(kv_heads, {"block_h": bh})
-        fn = lambda: ops.paged_attention(q, k, v, tables, q_pos,
+        cl = _clamp_paged(kv_heads, hd, {"block_h": bh})
+        fn = lambda: ops.paged_attention(q, k, v, tables, q_pos, n_kv_heads=kv_heads,
                                          interpret=interpret, **cl)
         try:
             for _ in range(warmup):

@@ -472,6 +472,7 @@ def paged_attention(
     tables: jax.Array,
     q_pos: jax.Array,
     *,
+    n_kv_heads: int,
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
     interpret: Optional[bool] = None,
@@ -481,47 +482,54 @@ def paged_attention(
     walk over each row's physical blocks, no gathered KV copy. With
     ``k_scale``/``v_scale`` the int8 pool is dequantized inside the beat.
 
-    q: (B, C, Hq, D); k/v: one pool layer (n_phys, bs, Hkv, D); tables:
-    (B, T) int32; q_pos: (B, C) int32. ``**blocks`` overrides the autotuned
-    ``block_h`` (kv heads per grid step; "paged_attn" path).
+    q: (B, C, Hq, D); k/v: one lane-dense pool layer (n_phys, bs, lanes)
+    holding ``n_kv_heads`` heads side by side from lane 0, scales (n_phys,
+    bs, n_kv_heads); tables: (B, T) int32; q_pos: (B, C) int32.
+    ``**blocks`` overrides the autotuned ``block_h`` (kv heads per grid
+    step; "paged_attn" path).
 
     Tensor parallelism: attention is embarrassingly parallel over kv-head
     groups, so under an active model-axis mesh the call shard_maps with
-    every head dim split — each device runs the unmodified kernel on its
-    heads, bit-identical to the unsharded kernel. When the head counts
-    don't divide the axis it falls back to the pure-XLA gather oracle
-    (kernels/ref.py), which GSPMD partitions freely, and logs the fallback
-    once per shape (``tp_dispatch_log``)."""
+    q's head dim and the pool's lane dim split — a contiguous split of
+    unpadded rows is a split by head group — and each device runs the
+    unmodified kernel on its heads, bit-identical to the unsharded kernel.
+    When the head counts don't divide the axis, or the rows are padded, it
+    falls back to the pure-XLA gather oracle (kernels/ref.py), which GSPMD
+    partitions freely, and logs the fallback once per shape
+    (``tp_dispatch_log``)."""
     b, c, hq, d = q.shape
-    bs, hkv = k.shape[1], k.shape[2]
+    bs, hkv = k.shape[1], n_kv_heads
     bl = autotune.get_paged_blocks(
         b, tables.shape[1] * bs, bs, d, hkv, overrides=blocks or None)
     impl = functools.partial(paged_attn_kernel_call,
                              block_h=bl["block_h"],
                              interpret=_auto_interpret(interpret))
+    oracle = functools.partial(ref.paged_attention_ref, n_kv_heads=hkv)
     mesh = _tp_mesh()
     scales = () if k_scale is None else (k_scale, v_scale)
     if mesh is None:
-        return impl(q, k, v, tables, q_pos,
+        return impl(q, k, v, tables, q_pos, n_kv_heads=hkv,
                     k_scale=k_scale, v_scale=v_scale)
     tp = int(mesh.shape[TP_AXIS])
     shapes = (q.shape, k.shape, tables.shape)
-    if hq % tp or hkv % tp:
+    if hq % tp or hkv % tp or k.shape[2] != hkv * d:
         _note_tp_dispatch("paged_attn", shapes, "xla_fallback",
-                          f"heads {hq}q/{hkv}kv do not divide the model axis ({tp})")
-        return ref.paged_attention_ref(q, k, v, tables, q_pos, k_scale, v_scale)
+                          f"heads {hq}q/{hkv}kv in pool rows of {k.shape[2]} lanes "
+                          f"do not split by head over the model axis ({tp})")
+        return oracle(q, k, v, tables, q_pos, k_scale, v_scale)
     _note_tp_dispatch("paged_attn", shapes, "shard_map")
     hspec = PartitionSpec(None, None, TP_AXIS, None)
+    lanes = PartitionSpec(None, None, TP_AXIS)  # pool rows: head groups
 
     def sharded(qs, ks, vs, tbl, qp, *sc):
         ksc, vsc = sc if sc else (None, None)
-        return impl(qs, ks, vs, tbl, qp, k_scale=ksc, v_scale=vsc)
+        return impl(qs, ks, vs, tbl, qp, n_kv_heads=hkv // tp, k_scale=ksc, v_scale=vsc)
 
     fn = jax.shard_map(
         sharded,
         mesh=mesh,
-        in_specs=(hspec, hspec, hspec, PartitionSpec(), PartitionSpec())
-        + (hspec,) * len(scales),
+        in_specs=(hspec, lanes, lanes, PartitionSpec(), PartitionSpec())
+        + (lanes,) * len(scales),
         out_specs=hspec,
         check_vma=False,
     )

@@ -9,15 +9,25 @@ block table directly instead:
   operand: step ``(b, h, j)`` streams physical block ``table[b, j]`` of the
   pool into VMEM through a BlockSpec index map — only the blocks a row
   actually names are ever touched, and no gathered copy exists anywhere.
+- the pool is read in place: one layer is lane-dense,
+  ``(n_phys_blocks, block_size, row_lanes)`` with the kv heads side by side
+  and the row padded to whole lane tiles (models/base.PagedKVLayout). The
+  TPU stores such an array row-major, so a step's block — the whole row,
+  or ``(1, bs, block_h * D)`` for a head group of whole lane tiles — is a
+  slab of the pool as it lies, and XLA has no reason to copy the pool into
+  another layout around the call. Head ``h`` of the slab is the static
+  lane slice ``[h*D, (h+1)*D)``; the padding lanes are never read.
 - flash-style online softmax: per (row, kv-head-group) running ``(m, l,
   acc)`` state lives in VMEM scratch, folded block-by-block along the
   innermost grid dim and normalized once on the last block. The final
   partial block (and every pad/future position) is masked per query with
   ``kv_pos <= q_pos`` — for decode that is exactly ``kv_pos < valid_len``.
-- fused int8 dequant: with scale operands the k/v blocks arrive as int8 and
-  are dequantized in-VREG inside the beat (``q.astype(f32) * scale``, the
-  same element math as nn/attention._dequantize_kv), so the quantized pool
-  is never expanded to fp in HBM.
+- fused int8 dequant: with scale operands ``(n_phys_blocks, block_size,
+  n_kv_heads)`` the k/v blocks arrive as int8 and are dequantized in-VREG
+  inside the beat (``q.astype(f32) * scale``, the same element math as
+  nn/attention._dequantize_kv), so the quantized pool is never expanded to
+  fp in HBM. A scale block spans every head (its lane dim is whole); the
+  beat selects its head's column with an exact masked sum.
 - whole-block skip: blocks entirely past every query position of the row
   (``j * bs > max(q_pos)``, the row maximum being a second scalar-prefetch
   operand) skip the compute beat, so decode work scales with each row's
@@ -25,8 +35,8 @@ block table directly instead:
 - GQA without an in-kernel reshape: the wrapper lays q out as ``(B, Hkv,
   g*C, D)`` and the query positions as ``(B, g*C, 1)``, so every block keeps
   its last two dims whole (the (8, 128) rule) and each kv head of a step is
-  one 2-D ``(g*C, D) x (bs, D)`` contraction against the pool block's head
-  slice ``k_ref[0, :, h, :]``.
+  one 2-D ``(g*C, D) x (bs, D)`` contraction against the head's lane slice
+  of the pool block.
 
 One kernel serves both paged call sites: single-token decode is ``C = 1``
 with ``q_pos = position`` and chunked prefill is ``C = chunk`` with per-token
@@ -39,9 +49,13 @@ TPU the dots follow the matmul precision in effect: at the default, Mosaic
 runs f32 dots as bf16 passes (a few 1e-3 off a "highest" oracle on a v5e),
 at "highest" as f32 (~1e-6); chip_smoke.py checks both.
 
-TPU note: block_size and head_dim below the (8, 128) f32 tile pad in VMEM;
-the heuristic in kernels/autotune.py sizes ``block_h`` so a step's working
-set stays inside the sub-tile budget. CPU tests run ``interpret=True``.
+TPU note: a pool block's lane dim ``block_h * D`` must be a multiple of 128
+or the whole row, so ``block_h`` is clamped to such a divisor of Hkv
+(``legal_block_h``; smollm's 5 x 64 takes all five heads and the row's 384
+lanes, phi3's 32 x 96 any multiple of 4); block_size below 8 pads the
+sublanes. The heuristic in kernels/autotune.py sizes ``block_h`` so a
+step's working set stays inside the sub-tile budget. CPU tests run
+``interpret=True``.
 """
 from __future__ import annotations
 
@@ -53,9 +67,27 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["paged_attn_kernel_call"]
+from .beats import LANE
+
+__all__ = ["legal_block_h", "paged_attn_kernel_call", "pool_row_lanes"]
 
 NEG_INF = -1e30  # matches nn/attention.py masking
+
+
+def pool_row_lanes(n_kv_heads: int, head_dim: int) -> int:
+    """Lanes of one paged pool row: every kv head's ``head_dim`` values side
+    by side, padded to whole lane tiles (models/base.PagedKVLayout)."""
+    return -(-n_kv_heads * head_dim // LANE) * LANE
+
+
+def legal_block_h(kv_heads: int, hd: int, block_h: int) -> int:
+    """The largest kv-head group ``<= block_h`` that divides ``kv_heads`` and
+    whose pool slab of ``block_h * hd`` lanes the TPU can block: a multiple
+    of 128 lanes, or every head of the row."""
+    bh = max(1, min(int(block_h), kv_heads))
+    while bh > 1 and (kv_heads % bh or (bh * hd % LANE and bh != kv_heads)):
+        bh -= 1
+    return bh if bh * hd % LANE == 0 or bh == kv_heads else kv_heads
 
 
 def _paged_attn_kernel(tbl_ref, qmax_ref, qpos_ref, q_ref, k_ref, v_ref, *rest,
@@ -65,7 +97,7 @@ def _paged_attn_kernel(tbl_ref, qmax_ref, qpos_ref, q_ref, k_ref, v_ref, *rest,
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
     del tbl_ref  # consumed by the pool index maps
-    row, j = pl.program_id(0), pl.program_id(2)
+    row, hg, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     bh, rows, d = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
 
     @pl.when(j == 0)
@@ -80,13 +112,19 @@ def _paged_attn_kernel(tbl_ref, qmax_ref, qpos_ref, q_ref, k_ref, v_ref, *rest,
     def _update():
         kvp = j * bs + jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 1)
         mask = kvp <= qpos_ref[0]  # (rows, bs): causal + valid_len in one
+        kb = k_ref[0].astype(jnp.float32)  # (bs, lanes): bh heads from lane 0
+        vb = v_ref[0].astype(jnp.float32)
+        if quantized:
+            ks_all, vs_all = ks_ref[0], vs_ref[0]  # (bs, Hkv): every head
+            col = jax.lax.broadcasted_iota(jnp.int32, ks_all.shape, 1)
         for h in range(bh):  # static: one kv head and its query group
             q = q_ref[0, h].astype(jnp.float32)  # (g*C, D)
-            k = k_ref[0, :, h, :].astype(jnp.float32)  # (bs, D)
-            v = v_ref[0, :, h, :].astype(jnp.float32)
+            k = kb[:, h * d:(h + 1) * d]  # (bs, D): the head's lane slice
+            v = vb[:, h * d:(h + 1) * d]
             if quantized:
-                k = k * ks_ref[0, :, h, :]
-                v = v * vs_ref[0, :, h, :]
+                own = col == hg * bh + h  # one column: the sum is exact
+                k = k * jnp.sum(jnp.where(own, ks_all, 0.0), axis=1, keepdims=True)
+                v = v * jnp.sum(jnp.where(own, vs_all, 0.0), axis=1, keepdims=True)
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
             s = jnp.where(mask, s / jnp.sqrt(jnp.float32(d)), NEG_INF)
@@ -112,26 +150,27 @@ def paged_attn_kernel_call(
     tables: jax.Array,
     q_pos: jax.Array,
     *,
+    n_kv_heads: int,
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
     block_h: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """Block-table attention. q: ``(B, C, Hq, D)``; k/v: one pool layer
-    ``(n_phys_blocks, block_size, Hkv, D)`` (int8 when scales are given,
-    scales ``(n_phys_blocks, block_size, Hkv, 1)`` f32); tables ``(B, T)``
-    int32; q_pos ``(B, C)`` int32 logical positions. Returns
-    ``(B, C, Hq, D)`` in q's dtype. ``block_h`` = kv heads per grid step
-    (clamped to a divisor of Hkv)."""
+    """Block-table attention. q: ``(B, C, Hq, D)``; k/v: one lane-dense pool
+    layer ``(n_phys_blocks, block_size, lanes)`` holding ``n_kv_heads`` heads
+    side by side from lane 0 (int8 when scales are given, scales
+    ``(n_phys_blocks, block_size, n_kv_heads)`` f32); tables ``(B, T)``
+    int32; q_pos ``(B, C)`` int32 logical positions. Returns ``(B, C, Hq,
+    D)`` in q's dtype. ``block_h`` = kv heads per grid step (clamped by
+    ``legal_block_h``)."""
     b, c, hq, d = q.shape
-    _, bs, hkv, dk = k.shape
-    assert d == dk and hq % hkv == 0, (q.shape, k.shape)
+    _, bs, width = k.shape
+    hkv = n_kv_heads
+    assert hkv * d <= width and hq % hkv == 0, (q.shape, k.shape, hkv)
     g = hq // hkv
     quantized = k_scale is not None
     assert (v_scale is not None) == quantized, "k_scale/v_scale come together"
-    bh = max(1, min(int(block_h or hkv), hkv))
-    while hkv % bh:
-        bh -= 1
+    bh = legal_block_h(hkv, d, block_h or hkv)
     t = tables.shape[1]
     rows = g * c
     q_pos = q_pos.astype(jnp.int32)
@@ -150,18 +189,23 @@ def paged_attn_kernel_call(
         return (bb, 0, 0)
 
     def kmap(bb, hh, jj, tbl, qm):  # pool: the table names the physical block
-        return (tbl[bb, jj], 0, hh, 0)
+        return (tbl[bb, jj], 0, hh)
 
+    def smap(bb, hh, jj, tbl, qm):  # scales: the block's every head
+        return (tbl[bb, jj], 0, 0)
+
+    # a head group of whole lane tiles, or every head: the whole padded row
+    lanes = width if bh == hkv else bh * d
     in_specs = [
         pl.BlockSpec((1, rows, 1), pmap),
         pl.BlockSpec((1, bh, rows, d), hmap),
-        pl.BlockSpec((1, bs, bh, d), kmap),
-        pl.BlockSpec((1, bs, bh, d), kmap),
+        pl.BlockSpec((1, bs, lanes), kmap),
+        pl.BlockSpec((1, bs, lanes), kmap),
     ]
     args = [tables.astype(jnp.int32), q_max, qp_rows, qg, k, v]
     if quantized:
-        in_specs += [pl.BlockSpec((1, bs, bh, 1), kmap),
-                     pl.BlockSpec((1, bs, bh, 1), kmap)]
+        in_specs += [pl.BlockSpec((1, bs, hkv), smap),
+                     pl.BlockSpec((1, bs, hkv), smap)]
         args += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

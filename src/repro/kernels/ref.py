@@ -75,6 +75,8 @@ def paged_attention_ref(
     q_pos: jax.Array,
     k_scale: jax.Array = None,
     v_scale: jax.Array = None,
+    *,
+    n_kv_heads: int,
 ) -> jax.Array:
     """Gather-based oracle for the fused paged-attention kernel: assemble
     each row's logical KV window from the block pool, then one full (not
@@ -82,21 +84,22 @@ def paged_attention_ref(
     so GSPMD partitions it freely — it doubles as the tensor-parallel
     fallback when head counts don't divide the model axis.
 
-    q: (B, C, Hq, D); k/v: (n_phys, bs, Hkv, D); tables: (B, T) int32;
-    q_pos: (B, C) int32; scales: (n_phys, bs, Hkv, 1) f32 for int8 pools.
+    q: (B, C, Hq, D); k/v: one lane-dense pool layer (n_phys, bs, lanes),
+    ``n_kv_heads`` heads side by side from lane 0; tables: (B, T) int32;
+    q_pos: (B, C) int32; scales: (n_phys, bs, n_kv_heads) f32 for int8 pools.
     """
     b, c, hq, d = q.shape
-    bs, hkv = k.shape[1], k.shape[2]
+    bs, hkv = k.shape[1], n_kv_heads
     g = hq // hkv
 
-    def gather(leaf):  # (B, T, bs, H, D|1) -> (B, T*bs, H, D|1)
-        w = leaf[tables]
-        return w.reshape(b, w.shape[1] * bs, *w.shape[3:])
+    def gather(leaf, width):  # (B, T, bs, lanes) -> (B, T*bs, Hkv, width)
+        w = leaf[tables][..., :hkv * width]
+        return w.reshape(b, w.shape[1] * bs, hkv, width)
 
-    kw, vw = gather(k), gather(v)
+    kw, vw = gather(k, d), gather(v, d)
     if k_scale is not None:
-        kw = kw.astype(jnp.float32) * gather(k_scale)
-        vw = vw.astype(jnp.float32) * gather(v_scale)
+        kw = kw.astype(jnp.float32) * gather(k_scale, 1)
+        vw = vw.astype(jnp.float32) * gather(v_scale, 1)
     qg = q.astype(jnp.float32).reshape(b, c, hkv, g, d)
     s = jnp.einsum("bchgd,bthd->bchgt", qg, kw.astype(jnp.float32),
                    preferred_element_type=jnp.float32)

@@ -10,6 +10,7 @@ from typing import Any, Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.paged_attn import pool_row_lanes
 from repro.nn.linear import LinearSpec
 from repro.nn.module import P, unbox
 
@@ -18,9 +19,10 @@ __all__ = [
     "KVCacheLayout",
     "KV_CACHE_LOGICAL_AXES",
     "ModelAPI",
+    "PAGED_KV_LOGICAL_AXES",
     "PagedKVLayout",
+    "init_paged_kv",
     "kv_cache_layout",
-    "paged_kv_layout",
     "stack_layers",
     "scan_blocks",
     "scan_blocks_aux",
@@ -54,16 +56,37 @@ KV_CACHE_LOGICAL_AXES = ("layers", None, None, "kv_heads", None)
 
 
 class PagedKVLayout(NamedTuple):
-    """Layout contract of the *paged* KV block pool (serve/paged_kv.py):
-    every leaf is ``(n_layers, n_phys_blocks, block_size, n_kv_heads,
-    head_dim)`` — the slot axis of the dense contract becomes a pool of
-    physical blocks and the position axis shrinks to one block. A request's
-    logical cache of ``max_len`` positions is the concatenation of the
-    ``max_len // block_size`` blocks named by its host-side block table;
-    block ``n_phys_blocks - 1`` is the reserved parking block that inactive
-    decode rows write junk into. Sharding is the dense contract's:
-    ``kv_heads`` over ``model``, everything else local (the gather/scatter
-    dims — blocks, offsets — never cross devices).
+    """Layout contract of the *paged* KV block pool (serve/paged_kv.py).
+
+    Every payload leaf (``k``, ``v``) is ``(n_layers, n_phys_blocks,
+    block_size, row_lanes)``: the kv heads are folded into the minor (lane)
+    dim, head ``h`` being lanes ``[h*head_dim, (h+1)*head_dim)``, and the row
+    is padded with zero lanes to ``row_lanes``, the next multiple of 128 (a
+    TPU lane tile) of ``n_kv_heads * head_dim``. An int8 pool adds
+    ``k_scale``/``v_scale`` leaves ``(n_layers, n_phys_blocks, block_size,
+    n_kv_heads)``, one scale per position and head. A request's logical cache
+    of ``max_len`` positions is the concatenation of the ``max_len //
+    block_size`` blocks named by its host-side block table; block
+    ``n_phys_blocks - 1`` is the reserved parking block that inactive decode
+    rows write junk into.
+
+    Why lane-dense and padded: the TPU stores an array in the layout that
+    pads it least. A block of whole rows of a multiple of 128 lanes is
+    stored row-major, ``(8, 128)`` tiles over ``(block_size, row_lanes)``,
+    which is how the fused kernel's BlockSpec reads it (kernels/paged_attn.py),
+    so the kernel reads the pool in place and the decode and chunk scatters
+    write whole rows in place. A minor pair ``(n_kv_heads, head_dim)``, or
+    rows of smollm's unpadded 320 lanes, would pad; the TPU then stores the
+    pool block-minor, and every program copies each layer's k and v into
+    the kernel's layout and back. The padding costs nothing the row-major
+    tiles would not: 320 lanes occupy 384 either way.
+
+    Sharding (``PAGED_KV_LOGICAL_AXES``): the lane dim maps to ``kv_heads``.
+    A contiguous split of unpadded rows is a split by head group when
+    ``n_kv_heads`` divides the mapped mesh axes; padded rows, or heads that
+    do not divide, replicate (``distributed.sharding.paged_kv_shardings``).
+    Blocks and offsets stay local, so the paged gather/scatter never cross
+    devices. The dense slot cache keeps ``KVCacheLayout``.
     """
 
     n_layers: int
@@ -71,6 +94,29 @@ class PagedKVLayout(NamedTuple):
     block_size: int
     n_kv_heads: int
     head_dim: int
+
+    @property
+    def kv_width(self) -> int:
+        """Lanes that hold k or v: every kv head's ``head_dim`` values."""
+        return self.n_kv_heads * self.head_dim
+
+    @property
+    def row_lanes(self) -> int:
+        """Lanes of one stored pool row: ``kv_width`` padded to whole lane tiles."""
+        return pool_row_lanes(self.n_kv_heads, self.head_dim)
+
+    def check(self, pool) -> None:
+        """Raise unless every leaf of the block-pool pytree ``pool`` has this
+        layout's shape."""
+        lead = (self.n_layers, self.n_phys_blocks, self.block_size)
+        for name, leaf in pool.items():
+            lanes = self.n_kv_heads if name.endswith("_scale") else self.row_lanes
+            if tuple(leaf.shape) != lead + (lanes,):
+                raise ValueError(f"pool leaf {name!r} has shape {tuple(leaf.shape)}, not "
+                                 f"{lead + (lanes,)} ({self})")
+
+# Logical sharding axes of the paged pool's rank-4 leaves (see PagedKVLayout).
+PAGED_KV_LOGICAL_AXES = ("layers", None, None, "kv_heads")
 
 
 def kv_cache_layout(cache) -> KVCacheLayout:
@@ -95,11 +141,19 @@ def kv_cache_layout(cache) -> KVCacheLayout:
     return KVCacheLayout(*k.shape)
 
 
-def paged_kv_layout(cache) -> PagedKVLayout:
-    """Read the paged layout off a block-pool pytree. Structurally the pool
-    IS a dense cache with (slots, max_len) = (n_phys_blocks, block_size) —
-    the same rank-5 validation applies; only the interpretation differs."""
-    return PagedKVLayout(*kv_cache_layout(cache))
+def init_paged_kv(layout: PagedKVLayout, *, dtype=jnp.float32, quantized: bool = False):
+    """A zeroed block pool in the ``PagedKVLayout`` contract: ``{"k", "v"}``
+    in ``dtype``, or int8 payloads with f32 ``k_scale``/``v_scale``."""
+    lead = (layout.n_layers, layout.n_phys_blocks, layout.block_size)
+    if quantized:
+        return {
+            "k": jnp.zeros(lead + (layout.row_lanes,), jnp.int8),
+            "v": jnp.zeros(lead + (layout.row_lanes,), jnp.int8),
+            "k_scale": jnp.zeros(lead + (layout.n_kv_heads,), jnp.float32),
+            "v_scale": jnp.zeros(lead + (layout.n_kv_heads,), jnp.float32),
+        }
+    return {"k": jnp.zeros(lead + (layout.row_lanes,), dtype),
+            "v": jnp.zeros(lead + (layout.row_lanes,), dtype)}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -185,6 +239,10 @@ class ModelAPI(NamedTuple):
     prefill: Optional[Callable[..., Any]] = None  # (params, batch, max_len) -> cache
     apply_aux: Optional[Callable[[Any, Any], Any]] = None  # -> (logits, aux_loss)
     # paged serving (families with attention KV only — see PagedKVLayout):
+    # (n_phys_blocks, block_size) -> the pool's PagedKVLayout
+    paged_kv_layout: Optional[Callable[[int, int], PagedKVLayout]] = None
+    # (n_phys_blocks, block_size, *, quantized, dtype) -> zeroed block pool
+    init_paged_cache: Optional[Callable[..., Any]] = None
     # (params, tok (S,1), pool, positions (S,), tables (S,T)) -> (logits, pool)
     decode_paged: Optional[Callable[..., Any]] = None
     # (params, chunk (1,C), pool, table (1,T), start (1,), last_in_chunk (1,))

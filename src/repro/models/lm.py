@@ -31,6 +31,8 @@ from repro.nn.moe import MoEConfig, moe_apply, moe_init
 from .base import (
     ArchConfig,
     ModelAPI,
+    PagedKVLayout,
+    init_paged_kv,
     make_norm,
     scan_blocks_aux,
     scan_blocks_with_cache,
@@ -198,6 +200,15 @@ def build_lm(cfg: ArchConfig, *, phase: str = "train") -> ModelAPI:
             lambda l: jnp.broadcast_to(l[None], (cfg.n_layers,) + l.shape), one
         )
 
+    def paged_kv_layout(n_phys_blocks: int, block_size: int) -> PagedKVLayout:
+        return PagedKVLayout(cfg.n_layers, n_phys_blocks, block_size, cfg.n_kv_heads, cfg.hd)
+
+    def init_paged_cache(n_phys_blocks: int, block_size: int, *, quantized: bool = False,
+                         dtype=None):
+        """The paged block pool (PagedKVLayout), lane-dense per layer."""
+        return init_paged_kv(paged_kv_layout(n_phys_blocks, block_size),
+                             dtype=dtype or cdtype, quantized=quantized)
+
     def decode_step(params, tokens, cache, position):
         """tokens: (B, 1) -> (logits (B, 1, V), new stacked cache)."""
         x = embedding.embed_apply(params["embed"], tokens, cdtype)
@@ -317,6 +328,8 @@ def build_lm(cfg: ArchConfig, *, phase: str = "train") -> ModelAPI:
         decode_step=decode_step,
         prefill=prefill,
         apply_aux=apply_aux,
+        paged_kv_layout=paged_kv_layout,
+        init_paged_cache=init_paged_cache,
         decode_paged=decode_paged,
         prefill_chunk=prefill_chunk,
         decode_verify=decode_verify,

@@ -500,14 +500,43 @@ def attn_verify_step(
 def paged_gather(leaf: jax.Array, table: jax.Array) -> jax.Array:
     """Assemble per-row logical KV views from a block pool.
 
-    leaf: ``(n_phys_blocks, block_size, H, D|1)`` (one layer of the pool);
-    table: ``(B, T)`` int32 block ids. Returns ``(B, T * block_size, H, D|1)``
-    where row ``i``'s position ``p`` is ``leaf[table[i, p // bs], p % bs]`` —
-    exactly the dense slot row the block writes were scattered from, so
-    attention over the gathered view is bit-identical to the dense path.
+    leaf: one layer of the lane-dense pool (models/base.PagedKVLayout),
+    ``(n_phys_blocks, block_size, lanes)``: payload rows of every kv head's
+    ``head_dim`` values, padded to whole lane tiles, or ``n_kv_heads``
+    int8 scales; table: ``(B, T)`` int32 block ids. Returns ``(B, T *
+    block_size, lanes)`` where row ``i``'s position ``p`` is
+    ``leaf[table[i, p // bs], p % bs]`` — exactly the dense slot row the
+    block writes were scattered from, so attention over the gathered view
+    (``_unfold_heads``) is bit-identical to the dense path.
     """
-    g = leaf[table]  # (B, T, bs, H, D)
+    g = leaf[table]  # (B, T, bs, lanes)
     return g.reshape(g.shape[0], g.shape[1] * g.shape[2], *g.shape[3:])
+
+
+def _fold_heads(x: jax.Array, lanes: int) -> jax.Array:
+    """(..., H, D|1) -> (..., lanes): a pool row, heads side by side and
+    zero-padded to the leaf's ``lanes`` (PagedKVLayout.row_lanes)."""
+    row = x.reshape(x.shape[:-2] + (-1,))
+    return jnp.pad(row, [(0, 0)] * (row.ndim - 1) + [(0, lanes - row.shape[-1])])
+
+
+def _unfold_heads(x: jax.Array, n_kv_heads: int, head_dim: int) -> jax.Array:
+    """(..., lanes) -> (..., H, D): the heads of padded pool rows."""
+    return x[..., :n_kv_heads * head_dim].reshape(x.shape[:-1] + (n_kv_heads, head_dim))
+
+
+def _paged_gather_kv(cache, table: jax.Array, cfg: AttnConfig, dtype):
+    """The gather route's per-row ``(B, max_len, H, D)`` k and v views of
+    one pool layer, dequantized when the pool is int8."""
+
+    def view(name):
+        width = 1 if name.endswith("_scale") else cfg.head_dim
+        return _unfold_heads(paged_gather(cache[name], table), cfg.n_kv_heads, width)
+
+    if "k_scale" in cache:
+        return (_dequantize_kv(view("k"), view("k_scale"), dtype),
+                _dequantize_kv(view("v"), view("v_scale"), dtype))
+    return view("k").astype(dtype), view("v").astype(dtype)
 
 
 def attn_decode_step_paged(
@@ -521,20 +550,22 @@ def attn_decode_step_paged(
     *,
     phase: str = "serve",
 ):
-    """One-token decode against a paged block pool (PagedKVLayout leaves
-    ``(n_phys_blocks, block_size, H, D)`` for this layer).
+    """One-token decode against a paged block pool (this layer's
+    PagedKVLayout leaves: ``(n_phys_blocks, block_size, row_lanes)``
+    payloads, ``(n_phys_blocks, block_size, H)`` int8 scales).
 
     ``position``: (B,) per-row next-write positions; ``table``: (B, T) block
-    tables (T = max_len // block_size). The new K/V is scattered at physical
-    ``(table[i, p // bs], p % bs)``; then attention routes per
-    ``cfg.paged_route``: the default ``"fused"`` walks the block table
-    through the Pallas online-softmax kernel (one pass over the pool, no
-    gathered copy, fused int8 dequant — token-for-token the gather route's
-    outputs within float rounding), while ``"gather"`` assembles per-row
-    ``(B, max_len, ...)`` views — the same bytes, positions and masks as the
-    dense per-row ``attn_decode_step``, so gather outputs are bit-identical
-    to dense. Inactive rows must point their whole table at the reserved
-    parking block (their junk writes race only with each other). SWA is
+    tables (T = max_len // block_size). The new K/V is scattered as one
+    lane-dense row at physical ``(table[i, p // bs], p % bs)``; then
+    attention routes per ``cfg.paged_route``: the default ``"fused"`` walks
+    the block table through the Pallas online-softmax kernel (one pass over
+    the pool, read in place, no gathered copy, fused int8 dequant —
+    token-for-token the gather route's outputs within float rounding),
+    while ``"gather"`` assembles per-row ``(B, max_len, ...)`` views — the
+    same bytes, positions and masks as the dense per-row
+    ``attn_decode_step``, so gather outputs are bit-identical to dense.
+    Inactive rows must point their whole table at the reserved parking
+    block (their junk writes race only with each other). SWA is
     unsupported: a ring cache has no block-aligned logical order to page.
     """
     assert cfg.window is None, "paged decode does not support sliding-window caches"
@@ -554,8 +585,8 @@ def attn_decode_step_paged(
     off = position % bs
     quantized = "k_scale" in cache
 
-    def write(buf, upd):  # upd: (B, 1, H, D|1) scattered at per-row (blk, off)
-        return buf.at[blk, off].set(upd[:, 0].astype(buf.dtype))
+    def write(buf, upd):  # upd: (B, 1, H, D|1) -> one pool row at (blk, off)
+        return buf.at[blk, off].set(_fold_heads(upd[:, 0], buf.shape[-1]).astype(buf.dtype))
 
     if quantized:
         kq, ks = _quantize_kv(k)
@@ -571,22 +602,11 @@ def attn_decode_step_paged(
 
     if cfg.paged_route == "fused":
         out = kernel_ops.paged_attention(
-            q, new_cache["k"], new_cache["v"], table, pos,
+            q, new_cache["k"], new_cache["v"], table, pos, n_kv_heads=cfg.n_kv_heads,
             k_scale=new_cache.get("k_scale"), v_scale=new_cache.get("v_scale"),
         )
     else:
-        if quantized:
-            k_all = _dequantize_kv(
-                paged_gather(new_cache["k"], table),
-                paged_gather(new_cache["k_scale"], table), x.dtype,
-            )
-            v_all = _dequantize_kv(
-                paged_gather(new_cache["v"], table),
-                paged_gather(new_cache["v_scale"], table), x.dtype,
-            )
-        else:
-            k_all = paged_gather(new_cache["k"], table).astype(x.dtype)
-            v_all = paged_gather(new_cache["v"], table).astype(x.dtype)
+        k_all, v_all = _paged_gather_kv(new_cache, table, cfg, x.dtype)
         max_len = table.shape[1] * bs
         out = dot_attention(
             q,
@@ -649,8 +669,9 @@ def attn_prefill_chunk(
     off = lp % bs
     quantized = "k_scale" in cache
 
-    def write(buf, upd):  # upd: (B, C, H, D|1) scattered at (blk, off) pairs
-        return buf.at[blk, off].set(upd.astype(buf.dtype), mode="drop")
+    def write(buf, upd):  # upd: (B, C, H, D|1) -> pool rows at (blk, off) pairs
+        return buf.at[blk, off].set(_fold_heads(upd, buf.shape[-1]).astype(buf.dtype),
+                                    mode="drop")
 
     if quantized:
         kq, ks = _quantize_kv(k)
@@ -668,22 +689,11 @@ def attn_prefill_chunk(
         # the chunk's own keys were just scattered, so the block walk sees
         # them; intra-chunk causality is the same kv_pos <= q_pos mask
         out = kernel_ops.paged_attention(
-            q, new_cache["k"], new_cache["v"], table, lp,
+            q, new_cache["k"], new_cache["v"], table, lp, n_kv_heads=cfg.n_kv_heads,
             k_scale=new_cache.get("k_scale"), v_scale=new_cache.get("v_scale"),
         )
     else:
-        if quantized:
-            k_all = _dequantize_kv(
-                paged_gather(new_cache["k"], table),
-                paged_gather(new_cache["k_scale"], table), x.dtype,
-            )
-            v_all = _dequantize_kv(
-                paged_gather(new_cache["v"], table),
-                paged_gather(new_cache["v_scale"], table), x.dtype,
-            )
-        else:
-            k_all = paged_gather(new_cache["k"], table).astype(x.dtype)
-            v_all = paged_gather(new_cache["v"], table).astype(x.dtype)
+        k_all, v_all = _paged_gather_kv(new_cache, table, cfg, x.dtype)
         out = dot_attention(
             q,
             k_all,

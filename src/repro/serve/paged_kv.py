@@ -4,9 +4,12 @@ The dense slot pool (serve/kv.py) spends ``n_slots x max_len`` positions of
 device memory whether or not requests share content and forces admission to
 prefill whole prompts in one bucketed shot. ``PagedKVManager`` instead owns
 ONE device-resident *block pool* per the ``models/base.PagedKVLayout``
-contract — every leaf ``(n_layers, n_phys_blocks, block_size, kv_heads,
-hd)`` — and gives each slot a host-side *block table* naming which physical
-blocks hold its logical positions ``[0, max_len)``:
+contract — every payload leaf lane-dense, ``(n_layers, n_phys_blocks,
+block_size, row_lanes)`` with the kv heads side by side in rows padded to
+whole lane tiles, which the TPU stores row-major so the fused kernel reads
+it in place with no layout copy (DESIGN.md §6.1) — and gives each slot a
+host-side *block table* naming which physical blocks hold its logical
+positions ``[0, max_len)``:
 
 - **Allocation** is block-granular and host-side (heap free list, O(log n)).
   A request's full span (chunk-padded prompt + decode budget) is reserved at
@@ -39,9 +42,11 @@ corrupting a neighbor, and rejected-window rollback is pure position
 arithmetic — spec writes touch exactly the private block set normal decode
 would, never a shared prefix block.
 
-With ``mesh=`` the pool shards exactly like the dense contract —
-``kv_heads`` over ``model`` (divisibility fallback to replication); block
-and offset dims are local, so the paged gather/scatter never cross devices.
+With ``mesh=`` the pool's lane dim shards over ``model`` by head group
+when ``n_kv_heads`` divides the axis and the rows are unpadded, and
+replicates otherwise (``distributed.sharding.paged_kv_shardings``); block
+and offset dims are local, so the paged gather/scatter never cross
+devices.
 
 Numerics: with fp KV the paged engine is token-for-token identical to the
 dense continuous engine (tests/test_paged_kv.py). With ``quantized_kv`` it
@@ -61,7 +66,7 @@ import jax
 import numpy as np
 
 from repro.distributed.constraints import mesh_context
-from repro.models.base import PagedKVLayout, paged_kv_layout
+from repro.models.base import PagedKVLayout
 from repro.obs.trace import NULL_TRACER
 
 __all__ = ["PagedKVManager", "hash_prompt_blocks"]
@@ -82,8 +87,8 @@ def hash_prompt_blocks(prompt: np.ndarray, block_size: int) -> List[bytes]:
 
 def _copy_block_body(cache, src, dst):
     """Copy physical block ``src`` -> ``dst`` on every leaf (COW). The block
-    axis is dim 1 of the (layers, n_phys_blocks, block_size, ...) layout —
-    the copy spans every layer of the one block."""
+    axis is dim 1 of the (layers, n_phys_blocks, block_size, lanes) layout —
+    the copy spans every layer of the one block, all heads at once."""
 
     def one(buf):
         return buf.at[:, dst].set(buf[:, src])
@@ -127,23 +132,22 @@ class PagedKVManager:
         self.mesh = mesh
         # +1 physical row: the reserved parking block for inactive decode rows
         self.parking_block = self.n_blocks
-        self.cache = api.init_cache(self.n_blocks + 1, block_size, quantized=quantized)
+        self.layout: PagedKVLayout = api.paged_kv_layout(self.n_blocks + 1, block_size)
+        self.cache = api.init_paged_cache(self.n_blocks + 1, block_size, quantized=quantized)
+        self.layout.check(self.cache)
         if mesh is not None:
             from repro.distributed.sharding import (
-                ShardingRules, kv_cache_shardings, replicated_sharding,
+                ShardingRules, paged_kv_shardings, replicated_sharding,
             )
 
             self.rules = rules if rules is not None else ShardingRules()
-            self._cache_sh = kv_cache_shardings(mesh, self.cache, self.rules)
+            self._cache_sh = paged_kv_shardings(mesh, self.cache, self.layout, self.rules)
             self._rep = replicated_sharding(mesh)
             self.cache = jax.device_put(self.cache, self._cache_sh)
         else:
             self.rules = rules
             self._cache_sh = None
             self._rep = None
-        self.layout: PagedKVLayout = paged_kv_layout(self.cache)
-        assert self.layout.n_phys_blocks == self.n_blocks + 1, self.layout
-        assert self.layout.block_size == block_size, self.layout
         self._copy = None  # lazily-built pinned-shardings COW program (mesh)
         # -- host state --------------------------------------------------
         self._slot_free_heap: List[int] = list(range(n_slots))

@@ -5,6 +5,7 @@ validation, measured search persisting to the on-disk cache), engine-level
 token parity of the fused route against the gather route and the dense
 continuous oracle on every backend, the pinned quantized_kv+paged numeric
 bound vs the fp dense oracle, and tp=2 serving through the sharded kernel."""
+import functools
 import json
 import subprocess
 import sys
@@ -17,8 +18,9 @@ import pytest
 
 from repro.configs import get_smoke
 from repro.kernels import autotune, ops, ref
-from repro.kernels.paged_attn import paged_attn_kernel_call
+from repro.kernels.paged_attn import legal_block_h, paged_attn_kernel_call
 from repro.models import build_model
+from repro.models.base import PagedKVLayout
 from repro.nn.module import unbox
 from repro.serve.engine import Request, ServeEngine
 
@@ -26,8 +28,12 @@ KEY = jax.random.PRNGKey(11)
 
 
 def _case(rng, b, c, hq, hkv, d, bs, t, quantized):
-    """One synthetic paged-attention problem: shuffled physical pool, each
-    row's table naming t random distinct blocks, in-range query positions."""
+    """One synthetic paged-attention problem: shuffled physical pool in the
+    lane-dense layout ((n_phys, bs, lanes) payloads, hkv heads of d padded
+    to whole lane tiles, the padding NaN in f32 pools so a read of it
+    shows; (n_phys, bs, hkv) scales), each row's table naming t random
+    distinct blocks, in-range query positions."""
+    lanes = PagedKVLayout(1, 1, bs, hkv, d).row_lanes
     n_phys = b * t + 3
     q = jnp.asarray(rng.normal(size=(b, c, hq, d)), jnp.float32)
     tables = jnp.asarray(rng.permutation(n_phys)[: b * t].reshape(b, t), jnp.int32)
@@ -37,13 +43,14 @@ def _case(rng, b, c, hq, hkv, d, bs, t, quantized):
         start = rng.integers(0, t * bs - c, size=(b,))
         q_pos = jnp.asarray(start[:, None] + np.arange(c)[None], jnp.int32)
     if quantized:
-        k = jnp.asarray(rng.integers(-127, 128, size=(n_phys, bs, hkv, d)), jnp.int8)
-        v = jnp.asarray(rng.integers(-127, 128, size=(n_phys, bs, hkv, d)), jnp.int8)
-        ks = jnp.asarray(rng.uniform(1e-3, 2e-2, size=(n_phys, bs, hkv, 1)), jnp.float32)
-        vs = jnp.asarray(rng.uniform(1e-3, 2e-2, size=(n_phys, bs, hkv, 1)), jnp.float32)
+        k = jnp.asarray(rng.integers(-127, 128, size=(n_phys, bs, lanes)), jnp.int8)
+        v = jnp.asarray(rng.integers(-127, 128, size=(n_phys, bs, lanes)), jnp.int8)
+        ks = jnp.asarray(rng.uniform(1e-3, 2e-2, size=(n_phys, bs, hkv)), jnp.float32)
+        vs = jnp.asarray(rng.uniform(1e-3, 2e-2, size=(n_phys, bs, hkv)), jnp.float32)
     else:
-        k = jnp.asarray(rng.normal(size=(n_phys, bs, hkv, d)), jnp.float32)
-        v = jnp.asarray(rng.normal(size=(n_phys, bs, hkv, d)), jnp.float32)
+        k = jnp.asarray(rng.normal(size=(n_phys, bs, lanes)), jnp.float32)
+        v = jnp.asarray(rng.normal(size=(n_phys, bs, lanes)), jnp.float32)
+        k, v = k.at[..., hkv * d:].set(jnp.nan), v.at[..., hkv * d:].set(jnp.nan)
         ks = vs = None
     return q, k, v, tables, q_pos, ks, vs
 
@@ -57,30 +64,43 @@ def _case(rng, b, c, hq, hkv, d, bs, t, quantized):
     "b,c,hq,hkv,d,bs,t,quantized,block_h",
     [
         (2, 1, 4, 4, 16, 8, 4, False, None),  # MHA decode
-        (3, 1, 8, 2, 32, 16, 3, False, 1),    # GQA decode, block_h=1
-        (2, 1, 8, 4, 16, 8, 5, True, 2),      # int8 decode, partial heads
+        (3, 1, 8, 2, 128, 16, 3, False, 1),   # GQA decode, block_h=1
+        (2, 1, 8, 4, 64, 8, 5, True, 2),      # int8 decode, partial heads
         (2, 6, 4, 2, 16, 8, 4, False, None),  # chunked prefill
         (2, 5, 8, 4, 16, 8, 4, True, None),   # int8 chunked prefill
         (1, 1, 2, 2, 64, 16, 8, False, 2),    # long context
+        # the cells' GQA shapes: smollm 15q/5kv d=64, phi3 32q/32kv d=96
+        (2, 1, 15, 5, 64, 16, 3, False, None),
+        (2, 1, 15, 5, 64, 16, 3, True, None),
+        (1, 32, 15, 5, 64, 16, 4, False, None),
+        (1, 32, 15, 5, 64, 16, 4, True, None),
+        (2, 1, 32, 32, 96, 16, 2, False, None),
+        (2, 1, 32, 32, 96, 16, 2, True, 4),
+        (1, 32, 32, 32, 96, 16, 3, False, None),
+        (1, 32, 32, 32, 96, 16, 3, True, None),
     ],
 )
 def test_kernel_matches_gather_oracle(b, c, hq, hkv, d, bs, t, quantized, block_h):
     """Online-softmax block walk == full-softmax gather oracle to float
     rounding, fp32 and int8 pools, decode (C=1) and chunk (C>1) shapes."""
+    if block_h is not None:  # the case runs the head grouping it names
+        assert legal_block_h(hkv, d, block_h) == block_h
     rng = np.random.default_rng(b * 100 + c * 10 + hq)
     q, k, v, tables, q_pos, ks, vs = _case(rng, b, c, hq, hkv, d, bs, t, quantized)
-    out = paged_attn_kernel_call(q, k, v, tables, q_pos, k_scale=ks, v_scale=vs,
-                                 block_h=block_h, interpret=True)
-    want = ref.paged_attention_ref(q, k, v, tables, q_pos, k_scale=ks, v_scale=vs)
+    out = paged_attn_kernel_call(q, k, v, tables, q_pos, n_kv_heads=hkv, k_scale=ks,
+                                 v_scale=vs, block_h=block_h, interpret=True)
+    want = ref.paged_attention_ref(q, k, v, tables, q_pos, k_scale=ks, v_scale=vs,
+                                   n_kv_heads=hkv)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
 
 
 def test_kernel_block_h_sweep_identical():
-    """Every legal block_h gives the same answer — the knob is perf-only."""
+    """Every legal block_h gives the same answer — the knob is perf-only
+    (hd=128, so every divisor of the kv heads is a whole-lane slab)."""
     rng = np.random.default_rng(0)
-    q, k, v, tables, q_pos, _, _ = _case(rng, 2, 1, 8, 4, 16, 8, 4, False)
-    outs = [np.asarray(paged_attn_kernel_call(q, k, v, tables, q_pos,
+    q, k, v, tables, q_pos, _, _ = _case(rng, 2, 1, 8, 4, 128, 8, 4, False)
+    outs = [np.asarray(paged_attn_kernel_call(q, k, v, tables, q_pos, n_kv_heads=4,
                                               block_h=bh, interpret=True))
             for bh in (1, 2, 4)]
     np.testing.assert_allclose(outs[0], outs[1], atol=1e-6)
@@ -93,10 +113,10 @@ def test_kernel_partial_final_block_masked():
     rng = np.random.default_rng(1)
     q, k, v, tables, q_pos, _, _ = _case(rng, 1, 1, 2, 2, 16, 8, 3, False)
     q_pos = jnp.asarray([[11]], jnp.int32)  # mid block 1; block 2 fully dead
-    out = paged_attn_kernel_call(q, k, v, tables, q_pos, interpret=True)
+    out = paged_attn_kernel_call(q, k, v, tables, q_pos, n_kv_heads=2, interpret=True)
     kp = k.at[tables[0, 1], 4:].set(1e4).at[tables[0, 2]].set(1e4)
     vp = v.at[tables[0, 1], 4:].set(1e4).at[tables[0, 2]].set(1e4)
-    out_p = paged_attn_kernel_call(q, kp, vp, tables, q_pos, interpret=True)
+    out_p = paged_attn_kernel_call(q, kp, vp, tables, q_pos, n_kv_heads=2, interpret=True)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(out_p))
 
 
@@ -106,8 +126,9 @@ def test_ops_route_and_wrapper():
     assert ops.kernel_route("paged_attn") is ops.paged_attention
     rng = np.random.default_rng(2)
     q, k, v, tables, q_pos, ks, vs = _case(rng, 2, 1, 4, 2, 16, 8, 4, True)
-    out = ops.paged_attention(q, k, v, tables, q_pos, k_scale=ks, v_scale=vs)
-    want = ref.paged_attention_ref(q, k, v, tables, q_pos, k_scale=ks, v_scale=vs)
+    out = ops.paged_attention(q, k, v, tables, q_pos, n_kv_heads=2, k_scale=ks, v_scale=vs)
+    want = ref.paged_attention_ref(q, k, v, tables, q_pos, k_scale=ks, v_scale=vs,
+                                   n_kv_heads=2)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
 
@@ -123,10 +144,17 @@ def test_paged_autotune_key_heuristic_overrides():
     bl = autotune.heuristic_paged_blocks(4, 128, 16, 32, 6)
     assert 6 % bl["block_h"] == 0
     # overrides win but clamp to a divisor; unknown keys are rejected
-    assert autotune.get_paged_blocks(4, 128, 16, 32, 6,
+    assert autotune.get_paged_blocks(4, 128, 16, 128, 6,
                                      overrides={"block_h": 5}) == {"block_h": 3}
     with pytest.raises(TypeError):
         autotune.get_paged_blocks(4, 128, 16, 32, 6, overrides={"block_q": 8})
+    # the lane-dense pool: a head group is a slab of 128k lanes or the row
+    assert autotune.get_paged_blocks(4, 128, 16, 32, 6,
+                                     overrides={"block_h": 5}) == {"block_h": 6}
+    assert autotune.get_paged_blocks(4, 128, 16, 64, 6,
+                                     overrides={"block_h": 3}) == {"block_h": 2}
+    assert autotune.heuristic_paged_blocks(64, 2048, 16, 64, 5) == {"block_h": 5}
+    assert autotune.heuristic_paged_blocks(8, 1024, 16, 96, 32)["block_h"] in (4, 8, 16, 32)
 
 
 def test_paged_measured_search_persists(tmp_path, monkeypatch):
@@ -181,6 +209,34 @@ def test_paged_routes_token_identical(mode):
     assert outs["fused"] == outs["gather"] == outs["dense"], mode
 
 
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+@pytest.mark.parametrize("heads", [(6, 3, 16), (4, 4, 32)], ids=["gqa", "mha"])
+def test_paged_routes_token_identical_multihead(heads, quantized):
+    """With several kv heads side by side in each lane-dense pool row, the
+    fused route reads every head's lane slice as the gather route does:
+    token for token through the paged scheduler, and (f32 KV) the dense
+    continuous oracle too."""
+    n_heads, n_kv_heads, head_dim = heads
+    arch = get_smoke("smollm-360m", compute_mode="dense", remat=False).replace(
+        n_heads=n_heads, n_kv_heads=n_kv_heads, head_dim=head_dim)
+    params = unbox(build_model(arch, phase="serve").init(KEY))
+    rng = np.random.RandomState(8)
+    prompts = [rng.randint(0, arch.vocab, size=int(rng.randint(3, 20)))
+               .astype(np.int32) for _ in range(4)]
+    outs = {}
+    for route in ("fused", "gather"):
+        arch_r = arch.replace(paged_attn_route=route)
+        eng = ServeEngine(build_model(arch_r, phase="serve"), params, arch_r, max_len=32,
+                          engine="paged", n_slots=2, kv_block_size=8, prefill_chunk=8,
+                          quantized_kv=quantized)
+        outs[route] = _drain(eng, prompts, 6)
+    assert outs["fused"] == outs["gather"]
+    if not quantized:
+        eng = ServeEngine(build_model(arch, phase="serve"), params, arch, max_len=32,
+                          engine="continuous", n_slots=2)
+        assert outs["fused"] == _drain(eng, prompts, 6)
+
+
 def test_paged_byte_gauges_report(mode="dense"):
     """Satellite gauges: pool bytes, per-token bytes, in-use peak and the
     modeled decode HBM-bytes-per-token all populate; the fused route's
@@ -220,8 +276,10 @@ def test_int8_pool_context_per_byte():
                           quantized_kv=quant)
         bpt[quant] = eng.scheduler.kv.bytes_per_token
     ratio = bpt[False] / bpt[True]
-    # f32: 2*h*d*4 B/token; int8: 2*h*(d+4) B/token -> 4d/(d+4) = 3.76 @ d=32
-    assert ratio == pytest.approx(4 * arch.hd / (arch.hd + 4), rel=1e-6)
+    # rows of r = h*d lanes padded to whole lane tiles (PagedKVLayout): f32
+    # 2*r*4 B/token; int8 2*r + 2*h*4 B/token -> 4r/(r+4h) = 3.88 @ h=1, d=32
+    r = PagedKVLayout(1, 1, 8, arch.n_kv_heads, arch.hd).row_lanes
+    assert ratio == pytest.approx(4 * r / (r + 4 * arch.n_kv_heads), rel=1e-6)
     assert ratio > 3.5
 
 
@@ -258,7 +316,7 @@ def test_quantized_paged_bound_vs_dense_oracle():
     # int8 paged: chunked prefill + fused block-walk decode, one slot
     bs = 8
     t = max_len // bs
-    cache = api.init_cache(t + 1, bs, quantized=True)
+    cache = api.init_paged_cache(t + 1, bs, quantized=True)
     tables = jnp.asarray(np.arange(t, dtype=np.int32))[None]
     chunk = 8
     padded = np.zeros(((len(prompt) + chunk - 1) // chunk) * chunk, np.int32)
@@ -308,9 +366,10 @@ from jax.sharding import Mesh
 
 @pytest.mark.slow
 def test_fused_route_tp2_token_identical():
-    """Fused route on a (4, 2) data x model mesh (kv_heads=2 divides tp=2,
-    so the kernel runs under shard_map) == 1-device gather route, token for
-    token, dense and qnn8."""
+    """Fused route on a (4, 2) data x model mesh (kv_heads=2 divides tp=2
+    and the pool's rows of 2 x 64 lanes need no padding, so the kernel runs
+    under shard_map on one head per device) == 1-device gather route, token
+    for token, dense and qnn8."""
     out = _run_sub("""
     from repro.configs import get_smoke
     from repro.models import build_model
@@ -321,7 +380,7 @@ def test_fused_route_tp2_token_identical():
 
     def run(mode, mesh_, route):
         arch = get_smoke("smollm-360m", compute_mode=mode, remat=False).replace(
-            n_heads=4, n_kv_heads=2, head_dim=24, paged_attn_route=route)
+            n_heads=4, n_kv_heads=2, head_dim=64, paged_attn_route=route)
         api = build_model(arch, phase="serve")
         params = unbox(api.init(jax.random.PRNGKey(0)))
         eng = ServeEngine(api, params, arch, max_len=32, engine="paged",
@@ -357,10 +416,10 @@ def test_tp_route_choice_is_logged_once_per_shape(caplog, monkeypatch):
     S = jax.ShapeDtypeStruct
 
     def trace(hq, hkv):
-        args = (S((2, 1, hq, 16), f32), S((5, 8, hkv, 16), f32),
-                S((5, 8, hkv, 16), f32), S((2, 2), i32), S((2, 1), i32))
+        args = (S((2, 1, hq, 16), f32), S((5, 8, hkv * 16), f32),
+                S((5, 8, hkv * 16), f32), S((2, 2), i32), S((2, 1), i32))
         with jax.sharding.use_abstract_mesh(mesh):
-            out = jax.eval_shape(ops.paged_attention, *args)
+            out = jax.eval_shape(functools.partial(ops.paged_attention, n_kv_heads=hkv), *args)
         assert out.shape == (2, 1, hq, 16)
         return ("paged_attn", (args[0].shape, args[1].shape, args[3].shape))
 

@@ -14,7 +14,12 @@ import pytest
 
 from repro.configs import get_smoke
 from repro.models import build_model
-from repro.models.base import paged_kv_layout
+from repro.models.base import (
+    KV_CACHE_LOGICAL_AXES,
+    KVCacheLayout,
+    PagedKVLayout,
+    kv_cache_layout,
+)
 from repro.nn.module import unbox
 from repro.serve import (
     ChunkedPrefill,
@@ -336,7 +341,8 @@ def test_parking_block_and_layout_contract(lm):
     cfg, api, params = lm
     sched = PagedSlotScheduler(api, params, cfg, n_slots=2, max_len=32,
                                block_size=8, chunk=8)
-    lay = paged_kv_layout(sched.kv.cache)
+    lay = sched.kv.layout
+    lay.check(sched.kv.cache)
     assert lay.block_size == 8 and lay.n_kv_heads == cfg.n_kv_heads
     assert lay.n_phys_blocks == sched.kv.n_blocks + 1
     assert (sched.kv.tables == sched.kv.parking_block).all()
@@ -346,6 +352,99 @@ def test_parking_block_and_layout_contract(lm):
     sched.run()
     assert (sched.kv.tables == sched.kv.parking_block).all()  # re-parked
     assert sched.kv.n_free_slots == 2
+
+
+# ---------------------------------------------------------------------------
+# the lane-dense pool layout (models/base.PagedKVLayout) and the dense one
+# ---------------------------------------------------------------------------
+
+GQA = dict(n_heads=4, n_kv_heads=2, head_dim=16)  # two kv heads per pool row
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+def test_paged_pool_is_lane_dense(quantized):
+    """init_paged_cache folds the kv heads into the minor dim: payloads
+    (layers, blocks, block_size, row_lanes), kv_heads*hd lanes padded to a
+    multiple of 128, int8 scales (..., kv_heads); the model's layout
+    names the head split, and its check refuses other trees."""
+    cfg = get_smoke("smollm-360m", remat=False).replace(**GQA)
+    api = build_model(cfg, phase="serve")
+    pool = api.init_paged_cache(9, 8, quantized=quantized)
+    lead = (cfg.n_layers, 9, 8)
+    assert pool["k"].shape == pool["v"].shape == lead + (128,)
+    assert pool["k"].dtype == (jnp.int8 if quantized else jnp.float32)
+    if quantized:
+        assert pool["k_scale"].shape == pool["v_scale"].shape == lead + (2,)
+    lay = api.paged_kv_layout(9, 8)
+    lay.check(pool)
+    assert lay == PagedKVLayout(cfg.n_layers, 9, 8, 2, 16)
+    assert (lay.kv_width, lay.row_lanes) == (32, 128)
+    assert PagedKVLayout(1, 9, 16, 5, 64).row_lanes == 384  # smollm: 320 lanes
+    assert PagedKVLayout(1, 9, 16, 32, 96).row_lanes == 3072  # phi3: no padding
+    with pytest.raises(ValueError):
+        (lay._replace(n_kv_heads=3) if quantized else lay._replace(head_dim=128)).check(pool)
+    with pytest.raises(ValueError):
+        lay.check(api.init_cache(9, 8))  # the dense cache
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+def test_dense_slot_cache_layout_unchanged(quantized):
+    """The dense slot cache keeps its own contract: rank-5 (layers, slots,
+    max_len, kv_heads, hd|1) leaves, heads as a dim of their own."""
+    cfg = get_smoke("smollm-360m", remat=False).replace(**GQA)
+    api = build_model(cfg, phase="serve")
+    cache = api.init_cache(3, 16, quantized=quantized)
+    assert kv_cache_layout(cache) == KVCacheLayout(cfg.n_layers, 3, 16, 2, 16)
+    assert cache["k"].shape == (cfg.n_layers, 3, 16, 2, 16)
+    if quantized:
+        assert cache["k_scale"].shape == (cfg.n_layers, 3, 16, 2, 1)
+    assert KV_CACHE_LOGICAL_AXES == ("layers", None, None, "kv_heads", None)
+
+
+def test_paged_pool_shards_by_head_group():
+    """The pool's lane dim splits over the model axis only when the kv heads
+    divide it and the rows are unpadded (a contiguous split of the lanes is
+    then a split by head group); otherwise the pool replicates rather than
+    cut heads."""
+    from jax.sharding import AbstractMesh, PartitionSpec
+
+    from repro.distributed.sharding import paged_kv_shardings
+
+    mesh = AbstractMesh((2, 2), ("data", "model"))
+    split, whole = PartitionSpec(None, None, None, "model"), PartitionSpec()
+    for hkv, hd, spec in ((4, 32, split), (3, 128, whole), (2, 16, whole)):
+        lay = PagedKVLayout(1, 9, 8, hkv, hd)
+        pool = {"k": jax.ShapeDtypeStruct((1, 9, 8, lay.row_lanes), jnp.float32),
+                "k_scale": jax.ShapeDtypeStruct((1, 9, 8, hkv), jnp.float32)}
+        assert {n: sh.spec for n, sh in paged_kv_shardings(mesh, pool, lay).items()} \
+            == {"k": spec, "k_scale": spec}, (hkv, hd)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+def test_cow_copies_every_head_of_a_lane_dense_block(quantized):
+    """Copy-on-write in the lane-dense pool: the fresh block gets every lane
+    (all kv heads) of every leaf and layer of the shared one, and nothing
+    else changes."""
+    cfg = get_smoke("smollm-360m", remat=False).replace(**GQA)
+    api = build_model(cfg, phase="serve")
+    kv = PagedKVManager(api, n_slots=2, max_len=32, block_size=8, quantized=quantized)
+    rng = np.random.RandomState(14)
+    kv.cache = {name: jnp.asarray(rng.randint(-100, 100, leaf.shape), leaf.dtype)
+                for name, leaf in kv.cache.items()}
+    prompt = rng.randint(0, cfg.vocab, 17).astype(np.int32)
+    s0 = kv.alloc_slot()
+    kv.try_admit(s0, prompt, budget=4, chunk=8)
+    kv.register_prompt(s0, prompt)
+    s1 = kv.alloc_slot()
+    assert kv.try_admit(s1, prompt, budget=4, chunk=8) == 16
+    b0 = kv._slot_blocks[s1][0]
+    before = {name: np.asarray(leaf) for name, leaf in kv.cache.items()}
+    nb = kv.ensure_private(s1, 0)
+    assert nb != b0 and kv.cow_copies == 1
+    for name, leaf in kv.cache.items():
+        want = before[name].copy()
+        want[:, nb] = before[name][:, b0]
+        np.testing.assert_array_equal(np.asarray(leaf), want, err_msg=name)
 
 
 def test_hash_chain_commits_to_whole_prefix():
@@ -420,7 +519,7 @@ def test_paged_sharded_token_identical_all_backends_8dev():
 
     def run(mode, mesh_, engine):
         arch = get_smoke("smollm-360m", compute_mode=mode, remat=False).replace(
-            n_heads=4, n_kv_heads=2, head_dim=24)
+            n_heads=4, n_kv_heads=2, head_dim=64)
         if mode in ("bika", "bnn"):
             arch = arch.replace(pack_signs=True)
         if mode != "dense":

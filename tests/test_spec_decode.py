@@ -215,6 +215,21 @@ def test_spec_paged_rollback_across_block_boundary(lm):
     assert m.spec_accepted_tokens < m.spec_drafted_tokens  # rejections happened
 
 
+def test_spec_paged_parity_with_several_kv_heads():
+    """The paged verify window scatters and attends lane-dense pool rows
+    that hold several kv heads: spec decode stays token-for-token the
+    target-only decode."""
+    arch = get_smoke("smollm-360m", compute_mode="dense", remat=False).replace(
+        n_heads=4, n_kv_heads=2, head_dim=16)
+    tparams = unbox(build_model(arch, phase="train").init(KEY))
+    prompts = _prompts(arch.vocab, seed=3)
+    base, _ = _drain(arch, tparams, prompts, engine="paged", kv_block_size=4, max_new=10)
+    spec, eng = _drain(arch, tparams, prompts, engine="paged", kv_block_size=4,
+                       max_new=10, spec_draft="small", spec_k=3)
+    assert spec == base
+    assert eng.metrics.spec_rounds > 0
+
+
 # ---------------------------------------------------------------------------
 # observability: trace <-> metrics reconciliation
 # ---------------------------------------------------------------------------
